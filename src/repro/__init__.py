@@ -2,12 +2,8 @@
 
 Sharding-invariant RNG is load-bearing for the whole repo: with the legacy
 non-partitionable threefry, GSPMD splits the RNG counter differently per
-out-sharding, so ZeRO-3's dp-sharded parameter init draws *different
-values* than stage 0/1 on the same seed (breaking the "ZeRO changes
-sharding, not math" invariant and any multi-process launcher agreement).
-Partitionable threefry makes random draws a pure function of (key, shape)
-regardless of mesh/sharding, at no cost on this workload.
+out-sharding, so ZeRO-3's dp-sharded parameter init would draw *different
+values* than stage 0/1 on the same seed. JAX's default
+(``jax_threefry_partitionable=True``) makes random draws a pure function of
+(key, shape) regardless of mesh/sharding; do not turn it off.
 """
-import jax
-
-jax.config.update("jax_threefry_partitionable", True)

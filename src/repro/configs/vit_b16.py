@@ -27,6 +27,10 @@ def config() -> ModelConfig:
         num_classes=10,              # CIFAR-10 default; overridden per dataset
         norm_eps=1e-6,
         act="gelu",
+        # 197 tokens (196 patches + cls) fit one 256 flash tile; the
+        # default 512 tile would compute 6.7x the live score entries
+        attn_block_q=256,
+        attn_block_k=256,
     )
 
 

@@ -57,7 +57,8 @@ Two coupled pieces:
    Why not ``shard_map`` + ``jax.lax.ppermute``: manual collectives on a
    manual-subgroup axis combined with ``auto`` (GSPMD) axes hit an
    unimplemented path in the jaxlib 0.4.37 SPMD partitioner ("PartitionId
-   instruction is not supported" / IsManualSubgroup check failure). The
+   instruction is not supported" / IsManualSubgroup check failure). That
+   reason is unverified on jax 0.9.0, the version the repo now runs. The
    vectorized-device formulation produces the identical collective-permute
    schedule while keeping ZeRO / tensor-parallel sharding on the remaining
    axes fully composable; grads of chunk-local params stay pipe-sharded

@@ -37,14 +37,16 @@ Backward pass (the training hot path)
 ``flash_attention`` is a ``jax.custom_vjp`` built on the shared
 ``kernels.vjp`` harness: gradients never differentiate the
 interpreter/Mosaic forward. The forward additionally emits the per-row
-logsumexp ``lse = m + log(l)`` (fp32, shape (B,H,S)) so the backward
+logsumexp ``lse = m + log(l)`` (fp32, stored lane-broadcast as
+(B,H,S,LANES) so its blocks meet Mosaic's tiling rule) so the backward
 recomputes probabilities directly as ``P = exp(S·scale − lse)`` without
 re-running the online softmax. Two passes share the grid machinery:
 
 * **dq pass** — q-major pruned cells. The Δ = rowsum(dO ∘ O) preprocess is
   fused into the first cell of each q-row (an fp32 VMEM scratch reduction
   over the already-resident dO/O tiles — no separate XLA pass over
-  (B,H,S,D)) and emitted as a (B,H,S) by-product for the dk/dv pass. Per
+  (B,H,S,D)) and emitted as a (B,H,S,LANES) by-product for the dk/dv
+  pass. Per
   K-cell: ``dP = dO·Vᵀ``, ``dS = P ∘ (dP − Δ)``, ``dq += scale · dS·K``
   into an fp32 VMEM accumulator flushed at the last cell of the row.
 * **dk/dv pass** — k-major pruned cells over (k_block, group, q_block) with
@@ -132,9 +134,11 @@ def _tile_mask(causal, window, qi, ki, block_q, block_k, s, t):
         jnp.int32, (block_q, block_k), 0)
     k_pos = ki * block_k + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 1)
+    # scalar-gated terms as `~flag | term`: Mosaic cannot select between
+    # bool vectors, so no jnp.where on masks
     mask = (q_pos < s) & (k_pos < t)
-    mask &= jnp.where(causal > 0, k_pos <= q_pos, True)
-    mask &= jnp.where(window > 0, (q_pos - k_pos) < window, True)
+    mask &= (causal <= 0) | (k_pos <= q_pos)
+    mask &= (window <= 0) | ((q_pos - k_pos) < window)
     return mask
 
 
@@ -294,7 +298,7 @@ def _fwd_kernel(meta_ref, cq_ref, ck_ref, cf_ref,  # SMEM scalar prefetch
         # fully-masked rows (m never updated) get LSE_BIG so that the
         # backward's exp(s - lse) underflows to an exact 0
         lse = jnp.where(m > 0.5 * NEG_INF, m + jnp.log(l), LSE_BIG)
-        lse_ref[0, 0] = lse[:, 0]
+        lse_ref[0, 0] = vjp.to_lanes(lse)
 
 
 def _forward(spec, meta, q, k, v):
@@ -321,9 +325,6 @@ def _forward(spec, meta, q, k, v):
     def kv_map(bb, hh, c, meta, cq, ck, cf):
         return (bb, hh // g, ck[c], 0)
 
-    def lse_map(bb, hh, c, meta, cq, ck, cf):
-        return (bb, hh, cq[c])
-
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -336,7 +337,7 @@ def _forward(spec, meta, q, k, v):
             ],
             out_specs=[
                 pl.BlockSpec((1, 1, bq, dv), q_map),
-                pl.BlockSpec((1, 1, bq), lse_map),
+                pl.BlockSpec((1, 1, bq, vjp.LANES), q_map),
             ],
             scratch_shapes=[
                 pltpu.VMEM((bq, 1), jnp.float32),
@@ -346,9 +347,9 @@ def _forward(spec, meta, q, k, v):
         ),
         out_shape=[
             jax.ShapeDtypeStruct((b, h, s, dv), q.dtype),
-            jax.ShapeDtypeStruct((b, h, s), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, s, vjp.LANES), jnp.float32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=spec.interpret,
     )(meta, cq, ck, cf, q, k, v)
@@ -370,7 +371,7 @@ def _load_bwd_tiles(q_ref, k_ref, v_ref, do_ref, lse_ref,
     k = jnp.where(kv_ok, k_ref[0, 0].astype(jnp.float32), 0.0)
     v = jnp.where(kv_ok, v_ref[0, 0].astype(jnp.float32), 0.0)
     do = jnp.where(q_ok, do_ref[0, 0].astype(jnp.float32), 0.0)
-    lse = lse_ref[0, 0][:, None]                   # (bq, 1)
+    lse = vjp.from_lanes(lse_ref[0, 0])            # (bq, 1)
     return q, k, v, do, lse
 
 
@@ -418,7 +419,7 @@ def _dq_kernel(meta_ref, cq_ref, ck_ref, cf_ref,
         o = jnp.where(q_ok, o_ref[0, 0].astype(jnp.float32), 0.0)
         do = jnp.where(q_ok, do_ref[0, 0].astype(jnp.float32), 0.0)
         delta_scr[...] = jnp.sum(o * do, axis=-1, keepdims=True)
-        delta_ref[0, 0] = delta_scr[...][:, 0]
+        delta_ref[0, 0] = vjp.to_lanes(delta_scr[...])
 
     def _compute():
         q, k, v, do, lse = _load_bwd_tiles(
@@ -463,7 +464,7 @@ def _dkv_kernel(meta_ref, ck_ref, cg_ref, cq_ref, cf_ref,
         q, k, v, do, lse = _load_bwd_tiles(
             q_ref, k_ref, v_ref, do_ref, lse_ref,
             qi, ki, block_q, block_k, seq_q, seq_k)
-        delta = delta_ref[0, 0][:, None]           # (bq, 1)
+        delta = vjp.from_lanes(delta_ref[0, 0])    # (bq, 1)
         s_ = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32) * scale
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
@@ -506,9 +507,6 @@ def _backward_dq(spec, meta, q, k, v, do, out, lse):
     def kv_map(bb, hh, c, meta, cq, ck, cf):
         return (bb, hh // g, ck[c], 0)
 
-    def lse_map(bb, hh, c, meta, cq, ck, cf):
-        return (bb, hh, cq[c])
-
     dq_kernel = functools.partial(
         _dq_kernel, block_q=bq, block_k=bk, scale=d ** -0.5,
         seq_q=s, seq_k=t, block_skip=spec.block_skip,
@@ -524,12 +522,12 @@ def _backward_dq(spec, meta, q, k, v, do, out, lse):
                 pl.BlockSpec((1, 1, bk, d), kv_map),
                 pl.BlockSpec((1, 1, bk, dv_dim), kv_map),
                 pl.BlockSpec((1, 1, bq, dv_dim), q_map),
-                pl.BlockSpec((1, 1, bq), lse_map),
+                pl.BlockSpec((1, 1, bq, vjp.LANES), q_map),
                 pl.BlockSpec((1, 1, bq, dv_dim), q_map),
             ],
             out_specs=[
                 pl.BlockSpec((1, 1, bq, d), q_map),
-                pl.BlockSpec((1, 1, bq), lse_map),
+                pl.BlockSpec((1, 1, bq, vjp.LANES), q_map),
             ],
             scratch_shapes=[
                 pltpu.VMEM((bq, d), jnp.float32),
@@ -538,9 +536,9 @@ def _backward_dq(spec, meta, q, k, v, do, out, lse):
         ),
         out_shape=[
             jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
-            jax.ShapeDtypeStruct((b, h, s), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, s, vjp.LANES), jnp.float32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=spec.interpret,
     )(meta, cq, ck, cf, q, k, v, do, lse, out)
@@ -569,9 +567,6 @@ def _backward_dkv(spec, meta, q, k, v, do, lse, delta):
     def kv_map2(bb, kk, c, meta, ck, cg, cq, cf):
         return (bb, kk, ck[c], 0)
 
-    def lse_map2(bb, kk, c, meta, ck, cg, cq, cf):
-        return (bb, kk * g + cg[c], cq[c])
-
     dkv_kernel = functools.partial(
         _dkv_kernel, block_q=bq, block_k=bk, scale=d ** -0.5,
         seq_q=s, seq_k=t, block_skip=spec.block_skip,
@@ -587,8 +582,8 @@ def _backward_dkv(spec, meta, q, k, v, do, lse, delta):
                 pl.BlockSpec((1, 1, bk, d), kv_map2),
                 pl.BlockSpec((1, 1, bk, dv_dim), kv_map2),
                 pl.BlockSpec((1, 1, bq, dv_dim), q_map2),
-                pl.BlockSpec((1, 1, bq), lse_map2),
-                pl.BlockSpec((1, 1, bq), lse_map2),
+                pl.BlockSpec((1, 1, bq, vjp.LANES), q_map2),
+                pl.BlockSpec((1, 1, bq, vjp.LANES), q_map2),
             ],
             out_specs=[
                 pl.BlockSpec((1, 1, bk, d), kv_map2),
@@ -603,7 +598,7 @@ def _backward_dkv(spec, meta, q, k, v, do, lse, delta):
             jax.ShapeDtypeStruct((b, kh, t, d), k.dtype),
             jax.ShapeDtypeStruct((b, kh, t, dv_dim), v.dtype),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=spec.interpret,
     )(meta, ck2, cg2, cq2, cf2, q, k, v, do, lse, delta)
@@ -654,7 +649,8 @@ def _flash_call(spec, meta, q, k, v):
 
 @functools.partial(jax.jit, static_argnums=(0,))
 def _forward_call(spec, meta, q, k, v):
-    return _forward(spec, meta, q, k, v)
+    out, lse = _forward(spec, meta, q, k, v)
+    return out, lse[..., 0]
 
 
 def flash_attention(q, k, v, *, causal=True, window=0, block_q=128,

@@ -10,7 +10,9 @@ Backward pass
 -------------
 ``fused_rmsnorm`` is a ``jax.custom_vjp`` built on the shared
 ``kernels.vjp`` harness. The forward emits the per-row inverse RMS
-``rinv = (mean(x²)+eps)^{-1/2}`` (fp32, one scalar per row) as a residual,
+``rinv = (mean(x²)+eps)^{-1/2}`` (fp32, one scalar per row, stored
+lane-broadcast as (rows, LANES) — Mosaic lays a 1-D operand out differently
+from XLA) as a residual,
 so the backward never redoes the row reduction: one row-tiled pass computes
 
     dx = rinv · (dy∘scale) − rinv³/D · x · rowsum(dy∘scale∘x)
@@ -52,7 +54,7 @@ def _fwd_kernel(x_ref, scale_ref, o_ref, rinv_ref, *, eps):
     rinv = 1.0 / jnp.sqrt(var + eps)               # (rows, 1) fp32
     o_ref[...] = ((x * rinv)
                   * scale_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
-    rinv_ref[...] = rinv[:, 0]
+    rinv_ref[...] = vjp.to_lanes(rinv)
 
 
 def _forward(spec, x, scale):
@@ -70,20 +72,20 @@ def _forward(spec, x, scale):
         grid=grid,
         in_specs=[
             pl.BlockSpec((br, d), lambda i: (i, 0)),
-            pl.BlockSpec((d,), lambda i: (0,)),
+            pl.BlockSpec((1, d), lambda i: (0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((br, d), lambda i: (i, 0)),
-            pl.BlockSpec((br,), lambda i: (i,)),
+            pl.BlockSpec((br, vjp.LANES), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((rows, d), x.dtype),
-            jax.ShapeDtypeStruct((rows,), jnp.float32),
+            jax.ShapeDtypeStruct((rows, vjp.LANES), jnp.float32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=spec.interpret,
-    )(x2, scale)
+    )(x2, scale.reshape(1, d))
     return out.reshape(orig_shape), rinv
 
 
@@ -102,10 +104,10 @@ def _bwd_kernel(x_ref, scale_ref, dy_ref, rinv_ref,
     ok = vjp.row_valid(i, block_rows, rows)
     x = jnp.where(ok, x_ref[...].astype(jnp.float32), 0.0)
     dy = jnp.where(ok, dy_ref[...].astype(jnp.float32), 0.0)
-    rinv = jnp.where(ok, rinv_ref[...][:, None], 0.0)   # (rows, 1)
-    s = scale_ref[...].astype(jnp.float32)
+    rinv = jnp.where(ok, vjp.from_lanes(rinv_ref[...]), 0.0)   # (rows, 1)
+    s = scale_ref[...].astype(jnp.float32)         # (1, D)
 
-    dys = dy * s[None, :]
+    dys = dy * s
     dot = jnp.sum(dys * x, axis=-1, keepdims=True)
     dx = rinv * dys - (rinv * rinv * rinv * dinv) * x * dot
     dx_ref[...] = dx.astype(dx_ref.dtype)
@@ -113,7 +115,7 @@ def _bwd_kernel(x_ref, scale_ref, dy_ref, rinv_ref,
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _final():
-        dsc_ref[...] = dsc_scr[0].astype(dsc_ref.dtype)
+        dsc_ref[...] = dsc_scr[...].astype(dsc_ref.dtype)
 
 
 def _backward(spec, x, scale, rinv, dy):
@@ -131,24 +133,24 @@ def _backward(spec, x, scale, rinv, dy):
         grid=grid,
         in_specs=[
             pl.BlockSpec((br, d), lambda i: (i, 0)),
-            pl.BlockSpec((d,), lambda i: (0,)),
+            pl.BlockSpec((1, d), lambda i: (0, 0)),
             pl.BlockSpec((br, d), lambda i: (i, 0)),
-            pl.BlockSpec((br,), lambda i: (i,)),
+            pl.BlockSpec((br, vjp.LANES), lambda i: (i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((br, d), lambda i: (i, 0)),
-            pl.BlockSpec((d,), lambda i: (0,)),
+            pl.BlockSpec((1, d), lambda i: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((rows, d), x.dtype),
-            jax.ShapeDtypeStruct((d,), scale.dtype),
+            jax.ShapeDtypeStruct((1, d), scale.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((1, d), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=spec.interpret,
-    )(x2, scale, dy2, rinv)
-    return dx.reshape(orig_shape), dscale
+    )(x2, scale.reshape(1, d), dy2, rinv)
+    return dx.reshape(orig_shape), dscale.reshape(d)
 
 
 # ---------------------------------------------------------------------------
@@ -187,6 +189,8 @@ def fused_rmsnorm(x, scale, *, eps=1e-6, block_rows=256, interpret=False):
 def fused_rmsnorm_fwd(x, scale, *, eps=1e-6, block_rows=256,
                       interpret=False):
     """Forward returning ``(out, rinv)`` — the fp32 per-row inverse-RMS
-    residual the backward consumes (exposed for tests/inspection)."""
+    residual the backward consumes, one scalar per row (exposed for
+    tests/inspection)."""
     spec = _Spec(int(block_rows), float(eps), bool(interpret))
-    return _forward(spec, x, scale)
+    out, rinv = _forward(spec, x, scale)
+    return out, rinv[:, 0]

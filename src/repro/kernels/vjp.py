@@ -19,8 +19,14 @@ instead of hand-rolling the plumbing:
   ``ACCUM_DTYPE`` (fp32) VMEM scratch regardless of input dtype and cast to
   the primal dtype only at the final flush — ``cast_grads_like`` enforces
   the custom_vjp contract that each cotangent matches its primal's aval.
+* **Lane-dense summaries**: Mosaic requires each block's last two
+  dimensions to be multiples of (8, 128) or the array's full extent, and
+  lays out 1-D operands differently from XLA. So every per-row fp32
+  summary (flash lse/Δ, rmsnorm inv-rms) is stored lane-broadcast as
+  ``(..., rows, LANES)``; ``to_lanes`` writes it and ``from_lanes`` reads
+  column 0 back as a ``(rows, 1)`` column.
 * **Interpret auto-detection**: ``auto_interpret(None)`` resolves to
-  interpret mode off-TPU (this CPU container) and compiled Mosaic on TPU.
+  interpret mode off-TPU (the CPU test backend) and compiled Mosaic on TPU.
 * **Block-size defaults from cfg**: ``attn_blocks`` / ``norm_block_rows`` /
   ``wkv_chunk`` pull tile sizes from a ``ModelConfig`` when one is in hand
   (the ops.py dispatch layer threads it through) with kernel-tuned
@@ -35,6 +41,9 @@ import jax.numpy as jnp
 import numpy as np
 
 ACCUM_DTYPE = jnp.float32
+
+# lane width of the per-row fp32 summaries (one TPU vreg row)
+LANES = 128
 
 # VMEM bound on the wkv6 pairwise-decay tile (chunk, chunk, P); see
 # configs/rwkv6_7b.py for the measurement that picked it.
@@ -60,6 +69,16 @@ def row_valid(idx, block, limit):
     reduction/matmul touches them."""
     rows = idx * block + jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)
     return rows < limit
+
+
+def to_lanes(col):
+    """(rows, 1) fp32 column -> (rows, LANES) lane-broadcast block value."""
+    return jnp.broadcast_to(col, (col.shape[0], LANES))
+
+
+def from_lanes(block):
+    """(rows, LANES) lane-broadcast block value -> (rows, 1) column."""
+    return block[:, :1]
 
 
 def cast_like(grad, primal):
@@ -130,7 +149,8 @@ def wkv_chunk(cfg=None, chunk=None):
 
 
 __all__ = [
-    "ACCUM_DTYPE", "WKV_CHUNK_MAX", "attn_blocks", "auto_interpret",
-    "cast_grads_like", "cast_like", "differentiable", "float0_like",
-    "norm_block_rows", "row_valid", "wkv_chunk",
+    "ACCUM_DTYPE", "LANES", "WKV_CHUNK_MAX", "attn_blocks",
+    "auto_interpret", "cast_grads_like", "cast_like", "differentiable",
+    "float0_like", "from_lanes", "norm_block_rows", "row_valid",
+    "to_lanes", "wkv_chunk",
 ]

@@ -50,6 +50,17 @@ class _Spec(NamedTuple):
     interpret: bool
 
 
+def _cumsum_rows(x, chunk, *, reverse=False):
+    """Inclusive prefix sum over the chunk (row) axis as a triangular
+    matmul: Mosaic has no cumsum lowering. ``reverse`` sums rows >= t."""
+    t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    j_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    keep = (j_idx >= t_idx) if reverse else (j_idx <= t_idx)
+    return jax.lax.dot(keep.astype(jnp.float32), x,
+                       precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
+
+
 # ---------------------------------------------------------------------------
 # forward kernel (chunked state recurrence; emits entering states residual)
 # ---------------------------------------------------------------------------
@@ -76,9 +87,9 @@ def _fwd_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
     k = k_ref[0, 0, 0].astype(jnp.float32)
     v = v_ref[0, 0, 0].astype(jnp.float32)
     w = w_ref[0, 0, 0].astype(jnp.float32)         # log-decay, <= 0
-    u = u_ref[0].astype(jnp.float32)               # (P,)
+    u = u_ref[0].astype(jnp.float32)               # (1, P)
 
-    L = jnp.cumsum(w, axis=0)                      # inclusive
+    L = _cumsum_rows(w, chunk)                     # inclusive
     lprev = L - w
     state = state_scr[...]
 
@@ -95,7 +106,7 @@ def _fwd_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
     o = o + jax.lax.dot(att, v, preferred_element_type=jnp.float32)
 
     # diagonal bonus
-    diag = jnp.sum(r * u[None, :] * k, axis=-1, keepdims=True)
+    diag = jnp.sum(r * u * k, axis=-1, keepdims=True)
     o = o + diag * v
 
     # state update: S <- diag(e^{L_end}) S + (k ⊙ e^{L_end - L})^T v
@@ -133,7 +144,7 @@ def _forward(spec, r, k, v, wlog, u, s0, *, with_states):
         return (bb, hh, ci, 0, 0)
 
     def u_map(bb, hh, ci):
-        return (hh, 0)
+        return (hh, 0, 0)
 
     def s0_map(bb, hh, ci):
         return (bb, hh, 0, 0)
@@ -160,16 +171,16 @@ def _forward(spec, r, k, v, wlog, u, s0, *, with_states):
             pl.BlockSpec((1, 1, 1, cs, p), rkvw_map),
             pl.BlockSpec((1, 1, 1, cs, p), rkvw_map),
             pl.BlockSpec((1, 1, 1, cs, p), rkvw_map),
-            pl.BlockSpec((1, p), u_map),
+            pl.BlockSpec((1, 1, p), u_map),
             pl.BlockSpec((1, 1, p, p), s0_map),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((p, p), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=spec.interpret,
-    )(rc, kc, vc, wc, u, s0)
+    )(rc, kc, vc, wc, u.reshape(h, 1, p), s0)
 
     o = _from_chunked(outs[0], b, s, h, p)
     s_end = outs[1]
@@ -195,12 +206,12 @@ def _bwd_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s_ref, do_ref, dsend_ref,
     k = k_ref[0, 0, 0].astype(jnp.float32)
     v = v_ref[0, 0, 0].astype(jnp.float32)
     w = w_ref[0, 0, 0].astype(jnp.float32)         # log-decay, <= 0
-    u = u_ref[0].astype(jnp.float32)               # (P,)
+    u = u_ref[0].astype(jnp.float32)               # (1, P)
     state = s_ref[0, 0, 0]                         # entering state (P, P) f32
     do = do_ref[0, 0, 0].astype(jnp.float32)       # (cs, P)
     g = g_scr[...]                                 # dL/dS_out of this chunk
 
-    L = jnp.cumsum(w, axis=0)
+    L = _cumsum_rows(w, chunk)
     lprev = L - w
     l_end = L[-1:, :]                              # (1, P)
     e_lprev = jnp.exp(lprev)
@@ -208,20 +219,23 @@ def _bwd_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s_ref, do_ref, dsend_ref,
     rdec = r * e_lprev
     kadv = k * e_adv
 
-    t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    j_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    tri = (j_idx < t_idx)[:, :, None]              # strictly causal (t, j, 1)
+    # strictly causal (t, j, 1), built 3-D: Mosaic cannot add a lane dim
+    # to a 2-D mask by reshape
+    t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk, 1), 0)
+    j_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk, 1), 1)
+    tri = (j_idx < t_idx).astype(jnp.float32)
     # live-triangle pairwise decay: lprev_t - L_j <= 0 for j < t, so no
-    # clip is needed once tri masks the upper triangle (and the masked
+    # clip is needed once tri zeroes the upper triangle (and the masked
     # entries' exp can't overflow: min() bounds them at 1)
-    pair = jnp.where(tri, jnp.exp(jnp.minimum(
-        lprev[:, None, :] - L[None, :, :], 0.0)), 0.0)  # (cs, cs, P)
+    pair = tri * jnp.exp(jnp.minimum(
+        lprev[:, None, :] - L[None, :, :], 0.0))   # (cs, cs, P)
 
     # --- intra-chunk attention adjoints ---
-    dA = jnp.where(tri[..., 0], jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32), 0.0)  # (t, j)
-    T1 = dA[:, :, None] * pair                     # (t, j, P)
+    # dA = dO·Vᵀ reduced over lanes with the lane dim kept; pair carries
+    # the causal mask
+    dA = jnp.sum(do[:, None, :] * v[None, :, :], axis=-1,
+                 keepdims=True)                    # (t, j, 1)
+    T1 = dA * pair                                 # (t, j, P)
     dr_att = jnp.sum(T1 * k[None, :, :], axis=1)   # (cs, P)
     dk_att = jnp.sum(T1 * r[:, None, :], axis=0)   # (cs, P)
     E = T1 * r[:, None, :] * k[None, :, :]         # dA ∘ ∂A/∂(lprev-L)
@@ -239,7 +253,7 @@ def _bwd_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s_ref, do_ref, dsend_ref,
 
     # --- dv: A' v term + state-update term + diagonal bonus ---
     att = jnp.sum(r[:, None, :] * pair * k[None, :, :], axis=-1)  # (t, j)
-    diag = jnp.sum(r * u[None, :] * k, axis=-1, keepdims=True)    # (cs, 1)
+    diag = jnp.sum(r * u * k, axis=-1, keepdims=True)             # (cs, 1)
     dov = jnp.sum(do * v, axis=-1, keepdims=True)                 # (cs, 1)
     dv = (jax.lax.dot_general(att, do, (((0,), (0,)), ((), ())),  # Aᵀ·dO
                               preferred_element_type=jnp.float32)
@@ -249,22 +263,22 @@ def _bwd_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s_ref, do_ref, dsend_ref,
     # --- dk / dr ---
     dkadv = jax.lax.dot_general(v, g, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # v·Gᵀ
-    dk = dk_att + dkadv * e_adv + u[None, :] * r * dov
-    dr = dr_att + drdec * e_lprev + u[None, :] * k * dov
+    dk = dk_att + dkadv * e_adv + u * r * dov
+    dr = dr_att + drdec * e_lprev + u * k * dov
     du_scr[...] += jnp.sum(r * k * dov, axis=0, keepdims=True)
 
     # --- decay gradients via the cumsum adjoint ---
     # w -> L = cumsum(w) -> {lprev = L - w, l_end = L[-1]}
     dlprev = drdec * rdec + dlprev_pair
     dl_end = (jnp.sum(dkadv * kadv, axis=0, keepdims=True)
-              + jnp.exp(l_end) * jnp.sum(state * g, axis=1)[None, :])
+              + jnp.exp(l_end) * jnp.sum((state * g).T, axis=0,
+                                         keepdims=True))
     last_row = (jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
                 == chunk - 1)
     dL_tot = (dL_pair - dkadv * kadv + dlprev
               + jnp.where(last_row, dl_end, 0.0))
     # reverse cumsum: dw_t = Σ_{j>=t} dL_j, minus the direct -w term of lprev
-    rev = jnp.sum(dL_tot, axis=0, keepdims=True) \
-        - jnp.cumsum(dL_tot, axis=0) + dL_tot
+    rev = _cumsum_rows(dL_tot, chunk, reverse=True)
     dw = rev - dlprev
 
     dr_ref[0, 0, 0] = dr.astype(dr_ref.dtype)
@@ -276,7 +290,7 @@ def _bwd_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s_ref, do_ref, dsend_ref,
     @pl.when(ci == num_chunks - 1)
     def _final():
         ds0_ref[0, 0] = g_scr[...].astype(ds0_ref.dtype)
-        du_ref[0, 0] = du_scr[0].astype(du_ref.dtype)
+        du_ref[0, 0] = du_scr[...].astype(du_ref.dtype)
 
 
 def _backward(spec, r, k, v, wlog, u, s0, states, do, ds_end):
@@ -291,7 +305,7 @@ def _backward(spec, r, k, v, wlog, u, s0, states, do, ds_end):
         return (bb, hh, nc - 1 - ci, 0, 0)
 
     def u_map(bb, hh, ci):
-        return (hh, 0)
+        return (hh, 0, 0)
 
     def pp_map(bb, hh, ci):
         return (bb, hh, 0, 0)
@@ -307,7 +321,7 @@ def _backward(spec, r, k, v, wlog, u, s0, states, do, ds_end):
             pl.BlockSpec((1, 1, 1, cs, p), rev_map),
             pl.BlockSpec((1, 1, 1, cs, p), rev_map),
             pl.BlockSpec((1, 1, 1, cs, p), rev_map),
-            pl.BlockSpec((1, p), u_map),
+            pl.BlockSpec((1, 1, p), u_map),
             pl.BlockSpec((1, 1, 1, p, p), states_map),
             pl.BlockSpec((1, 1, 1, cs, p), rev_map),
             pl.BlockSpec((1, 1, p, p), pp_map),
@@ -318,7 +332,7 @@ def _backward(spec, r, k, v, wlog, u, s0, states, do, ds_end):
             pl.BlockSpec((1, 1, 1, cs, p), rev_map),
             pl.BlockSpec((1, 1, 1, cs, p), rev_map),
             pl.BlockSpec((1, 1, p, p), pp_map),
-            pl.BlockSpec((1, 1, p), lambda bb, hh, ci: (bb, hh, 0)),
+            pl.BlockSpec((1, 1, 1, p), lambda bb, hh, ci: (bb, hh, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, nc, cs, p), r.dtype),
@@ -326,20 +340,20 @@ def _backward(spec, r, k, v, wlog, u, s0, states, do, ds_end):
             jax.ShapeDtypeStruct((b, h, nc, cs, p), v.dtype),
             jax.ShapeDtypeStruct((b, h, nc, cs, p), wlog.dtype),
             jax.ShapeDtypeStruct((b, h, p, p), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, p), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, 1, p), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((p, p), jnp.float32),
             pltpu.VMEM((1, p), jnp.float32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=spec.interpret,
-    )(rc, kc, vc, wc, u, states, doc, ds_end)
+    )(rc, kc, vc, wc, u.reshape(h, 1, p), states, doc, ds_end)
 
     dr, dk, dv, dw = (_from_chunked(x, b, s, h, p)
                       for x in (dr, dk, dv, dw))
-    du = jnp.sum(du_bh, axis=0)                    # fold batch outside
+    du = jnp.sum(du_bh, axis=0)[:, 0]              # fold batch outside
     return dr, dk, dv, dw, du, ds0
 
 

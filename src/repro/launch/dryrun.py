@@ -167,8 +167,6 @@ def run_pair(arch: str, shape_name: str, *, multi_pod: bool = False,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):   # jax<=0.4.x: one dict per program
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     totals = hlo_analysis.analyze(hlo)
     rl = roofline(totals, chips=chips, model_flops=model_flops)

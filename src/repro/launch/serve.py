@@ -7,17 +7,9 @@ sharded KV/state cache (the `serve_step` the decode input-shapes lower).
 from __future__ import annotations
 
 import argparse
-import os
-import sys
 import time
 
-
-def _maybe_reexec(devices: int):
-    if devices and os.environ.get("_REPRO_REEXEC") != "1":
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={devices}")
-        os.environ["_REPRO_REEXEC"] = "1"
-        os.execv(sys.executable, [sys.executable] + sys.argv)
+from repro.launch.train import use_compile_cache, use_host_devices
 
 
 def main():
@@ -27,10 +19,13 @@ def main():
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--gen", type=int, default=32)
-    ap.add_argument("--devices", type=int, default=0)
+    ap.add_argument("--devices", type=int, default=0,
+                    help="CPU only: run on N host devices")
     ap.add_argument("--model-axis", type=int, default=1)
     args = ap.parse_args()
-    _maybe_reexec(args.devices)
+    if args.devices:
+        use_host_devices(args.devices)
+    use_compile_cache()
 
     import jax
     import jax.numpy as jnp

@@ -1,13 +1,20 @@
 """End-to-end training driver (the paper's workload: DeepSpeed-style DP
 training of a ViT / LM on a mesh).
 
-Single-host usage (this container):
+On an accelerator the mesh spans every local device:
+    PYTHONPATH=src python -m repro.launch.train --arch vit-b16 \
+        --steps 50 --batch 64
+
+On CPU (tests, rehearsals):
     PYTHONPATH=src python -m repro.launch.train --arch vit-b16 --smoke \
         --steps 50 --batch 32 --accum 2 --devices 8
 
---devices N re-execs with xla_force_host_platform_device_count=N so the dp
-axis is real (the paper's "N GPUs"), which is how the scaling benchmarks
-and multi-device integration tests run on CPU.
+--devices N is CPU-only: it puts the run on the CPU backend with N host
+devices, so the dp axis is real (the paper's "N GPUs") in the scaling
+benchmarks and multi-device integration tests. Compiled programs persist
+in ``$JAX_COMPILATION_CACHE_DIR`` when it is set, else (accelerator runs)
+in ``.jax_cache/`` at the checkout root. ``main(argv)`` also serves
+in-process callers (chip_smoke.py): it returns a :class:`TrainRun`.
 
 --pp N enables 1F1B pipeline parallelism (core/pipeline.py): the layer
 stack splits into N contiguous stages over a `pipe` mesh axis carved out of
@@ -85,17 +92,53 @@ import json
 import os
 import sys
 import time
+from pathlib import Path
+from typing import Any, NamedTuple, Optional
+
+CHECKOUT_ROOT = Path(__file__).resolve().parents[3]
 
 
-def _maybe_reexec(devices: int):
-    if devices and os.environ.get("_REPRO_REEXEC") != "1":
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={devices}")
-        os.environ["_REPRO_REEXEC"] = "1"
-        os.execv(sys.executable, [sys.executable] + sys.argv)
+def use_host_devices(n: int):
+    """CPU only: put this process on the CPU backend with ``n`` host
+    devices (``--devices N``). Must run before the process touches a
+    backend; accelerator runs never call it and span every local
+    device."""
+    import jax
+    jax.config.update("jax_platforms", "cpu")
+    jax.config.update("jax_num_cpu_devices", n)
 
 
-def main():
+def use_compile_cache():
+    """Persist compiled programs across processes. An outside
+    ``JAX_COMPILATION_CACHE_DIR`` is honoured as is (JAX reads it).
+    Otherwise an accelerator run caches at a fixed path in the checkout,
+    because the path is part of the cache key; a CPU run does not cache,
+    since its compiles are cheap and XLA:CPU warns on every cached load."""
+    import jax
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR") and \
+            jax.default_backend() != "cpu":
+        jax.config.update("jax_compilation_cache_dir",
+                          str(CHECKOUT_ROOT / ".jax_cache"))
+
+
+class TrainRun(NamedTuple):
+    """What :func:`main` hands an in-process caller."""
+    history: list               # logged metric rows (train, then eval_*)
+    state: Any                  # final TrainState
+    mesh: Any
+    step_fn: Any                # the jitted train step the loop ran
+    last_batch: Any             # its last batch (None when no step ran)
+
+    def compiled_step_text(self) -> str:
+        """Optimized HLO of the train step for the shapes it ran with."""
+        with self.mesh:
+            return self.step_fn.lower(self.state, self.last_batch) \
+                .compile().as_text()
+
+
+def main(argv=None, *, devices: Optional[list] = None) -> TrainRun:
+    """Parse ``argv`` (default ``sys.argv[1:]``) and train. ``devices``
+    restricts the mesh to those devices (default: all local ones)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="vit-b16")
     ap.add_argument("--smoke", action="store_true",
@@ -107,7 +150,9 @@ def main():
     ap.add_argument("--zero", type=int, default=0)
     ap.add_argument("--optimizer", default="adamw")
     ap.add_argument("--lr", type=float, default=3e-4)
-    ap.add_argument("--devices", type=int, default=0)
+    ap.add_argument("--devices", type=int, default=0,
+                    help="CPU only: run on N host devices (0 = every "
+                         "local device of the default backend)")
     ap.add_argument("--model-axis", type=int, default=1)
     ap.add_argument("--pp", type=int, default=1,
                     help="pipeline stages (1F1B over the `pipe` mesh axis; "
@@ -212,16 +257,19 @@ def main():
     ap.add_argument("--guard-max-skips", type=int, default=3,
                     help="abort after this many consecutive guard-skipped "
                          "updates of the same batch")
-    args = ap.parse_args()
+    argv = sys.argv[1:] if argv is None else list(argv)
+    args = ap.parse_args(argv)
 
     if args.supervise:
-        # must run BEFORE _maybe_reexec / any jax import: the supervisor
-        # process only forks children and never touches the runtime
+        # the supervisor only starts children and never imports jax: the
+        # devices belong to the child
         from repro.resilience.supervisor import child_argv, supervise
-        raise SystemExit(supervise(child_argv(sys.argv[1:]),
+        raise SystemExit(supervise(child_argv(argv),
                                    max_restarts=args.max_restarts,
                                    seed=args.seed))
-    _maybe_reexec(args.devices)
+    if args.devices:
+        use_host_devices(args.devices)
+    use_compile_cache()
 
     import jax
     import jax.numpy as jnp
@@ -272,7 +320,8 @@ def main():
         spec = source.spec if source is not None else DATASETS["cifar10"]
         cfg = cfg.replace(num_classes=spec.num_classes,
                           label_smoothing=args.label_smoothing)
-    mesh = make_local_mesh(model=args.model_axis, pipe=args.pp)
+    mesh = make_local_mesh(model=args.model_axis, pipe=args.pp,
+                           devices=devices)
     dp = mesh.devices.shape[0]
     ecfg = EngineConfig(
         train_batch_size=args.batch,
@@ -291,7 +340,8 @@ def main():
         cfg, ecfg, mesh, aug=aug,
         preproc=source.preproc if source is not None else None)
     print(f"[train] arch={cfg.name} params={cfg.param_count()/1e6:.1f}M "
-          f"devices={mesh.devices.size} dp={dp} pp={args.pp} "
+          f"devices={mesh.devices.size}x{mesh.devices.flat[0].device_kind!r} "
+          f"dp={dp} pp={args.pp} "
           f"micro_batch={ecfg.derived_micro_batch(dp)} accum={args.accum} "
           f"zero={args.zero} opt={args.optimizer} "
           f"aug={'on' if aug else 'off'}")
@@ -396,9 +446,11 @@ def main():
         batch = pipe.device_put(pipe.batch_at(e, i))
         return batch, pipe.next_cursor(e, i)
 
+    batch = None
     try:
         with mesh:
             for step in range(start_step, end_step):
+                t_step = time.perf_counter()
                 batch, nxt = fetch(step)
                 # anomaly-guarded step: a non-finite loss/grad-norm makes
                 # the jitted step a bitwise no-op (step_ok=0) — retry the
@@ -429,8 +481,13 @@ def main():
                 state = state.replace(epoch=jnp.int32(nxt[0]),
                                       batch_index=jnp.int32(nxt[1]))
                 if step % args.log_every == 0 or step == end_step - 1:
+                    jax.block_until_ready(state)
+                    step_s = time.perf_counter() - t_step
                     m = {k: float(np.asarray(v)) for k, v in metrics.items()}
                     m["step"] = step
+                    # wall time of this step, input wait included
+                    m["step_s"] = step_s
+                    m["guard_skips"] = skips
                     m["wall_s"] = round(time.time() - t0, 2)
                     hist.append(m)
                     print(f"[train] step {step:5d} loss={m['loss']:.4f} "
@@ -481,6 +538,7 @@ def main():
     final = f"final loss {tr[-1]['loss']:.4f}" if tr \
         else f"no steps run (start={start_step}, end={end_step})"
     print(f"[train] done in {time.time()-t0:.1f}s; {final}")
+    return TrainRun(hist, state, mesh, step_fn, batch)
 
 
 if __name__ == "__main__":

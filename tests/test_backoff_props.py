@@ -101,7 +101,7 @@ if HAS_HYPOTHESIS:
         base_delay=st.floats(1e-3, 1.0),
         multiplier=st.floats(1.0, 4.0),
         max_delay=st.floats(1.0, 60.0),
-        jitter=st.floats(0.0, 1.0))
+        jitter=st.floats(0.0, 1.0, exclude_max=True))
 
     @needs_hypothesis
     @settings(max_examples=100, deadline=None)

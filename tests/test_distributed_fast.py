@@ -10,13 +10,13 @@ _COMMON = r"""
 import jax, jax.numpy as jnp
 from repro.configs import get_smoke_config, EngineConfig
 from repro.core.engine import DistributedEngine
+from repro.launch.mesh import make_local_mesh
 from repro.launch.specs import concrete_batch
 
 def run_steps(arch, mesh_shape, zero=0, steps=2, accum=2, pipe=1):
-    if pipe > 1:
-        mesh = jax.make_mesh(mesh_shape + (pipe,), ("data", "model", "pipe"))
-    else:
-        mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+    n = mesh_shape[0] * mesh_shape[1] * pipe
+    mesh = make_local_mesh(model=mesh_shape[1], pipe=pipe,
+                           devices=jax.devices()[:n])
     cfg = get_smoke_config(arch).replace(dtype="float32")
     ecfg = EngineConfig(train_batch_size=8, gradient_accumulation_steps=accum,
                         zero_stage=zero, lr=1e-3, total_steps=10,
@@ -82,16 +82,14 @@ import json, os, tempfile
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_smoke_config, EngineConfig
 from repro.core.engine import DistributedEngine
+from repro.launch.mesh import make_local_mesh
 from repro.checkpoint import checkpoint_size_report
 from repro.launch.specs import concrete_batch
 
 CFG = get_smoke_config("vit-b16").replace(dtype="float32")
 
 def make_engine(zero=0, pipe=1):
-    if pipe > 1:
-        mesh = jax.make_mesh((4 // pipe, pipe, 1), ("data", "pipe", "model"))
-    else:
-        mesh = jax.make_mesh((4, 1), ("data", "model"))
+    mesh = make_local_mesh(pipe=pipe, devices=jax.devices()[:4])
     ecfg = EngineConfig(train_batch_size=8, gradient_accumulation_steps=2,
                         zero_stage=zero, lr=1e-3, total_steps=10,
                         warmup_steps=1, pipeline_stages=pipe)
@@ -193,6 +191,7 @@ _EVAL = r"""
 import jax, numpy as np
 from repro.configs import get_smoke_config, EngineConfig
 from repro.core.engine import DistributedEngine
+from repro.launch.mesh import make_local_mesh
 from repro.data import AugmentConfig, CIFARSource, DataPipeline
 
 CFG = get_smoke_config("vit-b16").replace(dtype="float32")
@@ -202,10 +201,7 @@ def source():
     return CIFARSource("cifar10", seed=3, eval_size=EVAL_SIZE)
 
 def make_engine(dp, pipe=1, zero=0, aug=None):
-    if pipe > 1:
-        mesh = jax.make_mesh((dp, pipe, 1), ("data", "pipe", "model"))
-    else:
-        mesh = jax.make_mesh((dp, 1), ("data", "model"))
+    mesh = make_local_mesh(pipe=pipe, devices=jax.devices()[:dp * pipe])
     ecfg = EngineConfig(train_batch_size=8, gradient_accumulation_steps=2,
                         zero_stage=zero, lr=1e-3, total_steps=10,
                         warmup_steps=1, pipeline_stages=pipe)

@@ -9,11 +9,13 @@ _COMMON = r"""
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_smoke_config, EngineConfig
 from repro.core.engine import DistributedEngine
+from repro.launch.mesh import make_local_mesh
 from repro.launch.specs import concrete_batch
 
 def run_steps(arch, mesh_shape, zero, steps=3, seq_parallel="none",
               accum=1, model_axis_name="model"):
-    mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+    mesh = make_local_mesh(model=mesh_shape[1],
+                           devices=jax.devices()[:mesh_shape[0] * mesh_shape[1]])
     cfg = get_smoke_config(arch).replace(dtype="float32")
     ecfg = EngineConfig(train_batch_size=8, gradient_accumulation_steps=accum,
                         zero_stage=zero, lr=1e-3, total_steps=10,
@@ -108,6 +110,7 @@ def test_decode_sharded_cache():
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_smoke_config, EngineConfig
 from repro.core.engine import DistributedEngine
+from repro.launch.mesh import make_local_mesh
 from repro.models import transformer as model
 
 cfg = get_smoke_config("qwen2.5-14b").replace(dtype="float32")
@@ -115,7 +118,7 @@ params = model.init_params(cfg, jax.random.PRNGKey(0))
 toks = jax.random.randint(jax.random.PRNGKey(1), (4, 40), 0, cfg.vocab_size)
 ref, _, _ = model.forward(cfg, params, {"tokens": toks}, mode="train")
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_local_mesh(model=4)
 eng = DistributedEngine(cfg, EngineConfig(train_batch_size=8), mesh)
 with mesh:
     cache = model.init_cache(cfg, 4, 40, jnp.float32)

@@ -221,6 +221,7 @@ import json, os, tempfile
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_smoke_config, EngineConfig
 from repro.core.engine import DistributedEngine
+from repro.launch.mesh import make_local_mesh
 import repro.checkpoint as ck
 from repro.checkpoint.checkpoint import _flatten
 from repro.launch.specs import concrete_batch
@@ -228,10 +229,7 @@ from repro.launch.specs import concrete_batch
 CFG = get_smoke_config("vit-b16").replace(dtype="float32")
 
 def make_engine(zero=0, pipe=1):
-    if pipe > 1:
-        mesh = jax.make_mesh((8 // pipe, pipe, 1), ("data", "pipe", "model"))
-    else:
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+    mesh = make_local_mesh(pipe=pipe, devices=jax.devices()[:8])
     ecfg = EngineConfig(train_batch_size=16, gradient_accumulation_steps=2,
                         zero_stage=zero, lr=1e-3, total_steps=10,
                         warmup_steps=1, pipeline_stages=pipe)
