@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.configs.base import EngineConfig, ModelConfig
 from repro.core import pipeline as pipe
 from repro.core import sharding as shd
@@ -333,6 +334,9 @@ class DistributedEngine:
                     jax.random.fold_in(state.rng, state.step),
                     self.ecfg.gradient_accumulation_steps)
 
+                # scoped inside the differentiated function, so its
+                # backward reads transpose(jvp(forward)) (repro/obs.py)
+                @jax.named_scope(obs.FORWARD)
                 def mb_loss(p, mb, rng):
                     if self.aug is not None:
                         # on-device crop/flip/Mixup/CutMix — pure in the
@@ -352,6 +356,17 @@ class DistributedEngine:
                     mb_loss, compute_params, batch,
                     self.ecfg.gradient_accumulation_steps, grad_specs=gspecs,
                     rngs=mb_rngs)
+        with jax.named_scope(obs.OPTIMIZER):
+            new_params, new_opt, new_step, metrics = self._apply_update(
+                state, grads, metrics)
+        new_state = state.replace(params=new_params, opt_state=new_opt,
+                                  step=new_step)
+        return new_state, metrics
+
+    def _apply_update(self, state: TrainState, grads, metrics):
+        """The step's work after the gradients: lr schedule, clip, update
+        and the anomaly guard's selects."""
+        params, opt_state = state.params, state.opt_state
         lr = self.schedule(state.step)
         new_params, new_opt, gnorm = self.optimizer.update(
             grads, opt_state, params, lr)
@@ -377,9 +392,7 @@ class DistributedEngine:
             new_opt = sel(new_opt, opt_state)
             new_step = jnp.where(ok, new_step, state.step)
             metrics["step_ok"] = ok.astype(jnp.int32)
-        new_state = state.replace(params=new_params, opt_state=new_opt,
-                                  step=new_step)
-        return new_state, metrics
+        return new_params, new_opt, new_step, metrics
 
     def _pipeline_grads(self, compute_params, batch, gspecs, mb_rngs):
         """Mean grads + metrics via the staged 1F1B pipeline — numerically
@@ -393,6 +406,7 @@ class DistributedEngine:
         so only one microbatch's fp32 image tensor is live at a time."""
         pspecs = self._pspecs(self.init_abstract()[0])
 
+        @jax.named_scope(obs.FORWARD)
         def microbatch_fn(mb, rng):
             if self.aug is not None:
                 from repro.data.augment import augment_batch

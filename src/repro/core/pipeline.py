@@ -79,6 +79,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.core.grad_accum import split_microbatches
 from repro.models import transformer as model
 
@@ -404,9 +405,21 @@ def _staged_pipeline(cfg, params, batch, *, stages, num_micro, interleave,
         return model.stack_forward(cfg, chunk_stack, h, positions,
                                    chunk_windows)
 
+    # the model's forward, scoped inside the functions jax.vjp
+    # differentiates, so the backward slots read transpose(jvp(forward))
+    # as the dp route's backward does (repro/obs.py)
+    def stage_forward(sk, win, x):
+        with jax.named_scope(obs.FORWARD):
+            return jax.vmap(chunk_fn)(sk, win, x)
+
+    def embed(p, mb):
+        with jax.named_scope(obs.FORWARD):
+            return model.embed(cfg, p, mb)[0]
+
     def head_loss(p, h, mb):
-        logits = model.apply_head(cfg, p, h)
-        return model.loss_from_logits(cfg, logits, mb)
+        with jax.named_scope(obs.FORWARD):
+            logits = model.apply_head(cfg, p, h)
+            return model.loss_from_logits(cfg, logits, mb)
 
     def select_chunks(tasks):
         """Per-device chunk-round selection for one pass. Uniform rounds
@@ -514,14 +527,13 @@ def _staged_pipeline(cfg, params, batch, *, stages, num_micro, interleave,
                     entries.append(None)
                 elif task.chunk == 0:   # stage-0 inject (device 0 only)
                     entries.append(
-                        model.embed(cfg, params,
-                                    micro_batch(task.micro))[0])
+                        embed(params, micro_batch(task.micro)))
                 else:
                     entries.append(act.pop((task.chunk - 1, task.micro)))
             x = _constrain(
                 assemble(entries, -1, lambda b: b[-1:]), state_spec)
             sel, win, rounds = select_chunks(ftasks)
-            y = _constrain(jax.vmap(chunk_fn)(sel, win, x), state_spec)
+            y = _constrain(stage_forward(sel, win, x), state_spec)
             for d, task in enumerate(ftasks):
                 if task is None:
                     continue
@@ -572,7 +584,7 @@ def _staged_pipeline(cfg, params, batch, *, stages, num_micro, interleave,
             # the stored inputs, pull the output cotangents back — the
             # stored input is the ONLY residual that outlived the forward
             _, chunk_pb = jax.vjp(
-                lambda sk, xx: jax.vmap(chunk_fn)(sk, win, xx), sel, xb)
+                lambda sk, xx: stage_forward(sk, win, xx), sel, xb)
             sel_ct, x_ct = chunk_pb(g)
             if len(set(rounds)) == 1:
                 gstack = jax.tree.map(
@@ -590,8 +602,7 @@ def _staged_pipeline(cfg, params, batch, *, stages, num_micro, interleave,
                 if c == 0:
                     # cotangent reaches the inject: embed VJP (device 0)
                     _, emb_pb = jax.vjp(
-                        lambda p, _m=m: model.embed(
-                            cfg, p, micro_batch(_m))[0], params)
+                        lambda p, _m=m: embed(p, micro_batch(_m)), params)
                     (p_ct,) = emb_pb(x_ct[d])
                     gacc = acc_tree(gacc, p_ct)
                 else:

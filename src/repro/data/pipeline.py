@@ -32,6 +32,7 @@ from typing import Iterator, Optional, Tuple
 import jax
 import numpy as np
 
+from repro import obs
 from repro.data.synthetic import DatasetSpec, make_image_batch, \
     make_token_batch
 from repro.resilience import faults as _faults
@@ -262,20 +263,22 @@ class Prefetcher:
         return self
 
     def __next__(self):
-        while True:
-            try:
-                kind, item = self._q.get(timeout=0.1)
-                break
-            except queue.Empty:
-                if self._stop.is_set():
-                    raise StopIteration("prefetcher closed")
-                if not any(t.is_alive() for t in self._threads):
-                    # producers exited: already-delivered error consumed,
-                    # or they died before enqueueing — surface either way
-                    if self._error is not None:
-                        raise RuntimeError(
-                            "data prefetch thread failed") from self._error
-                    raise StopIteration("prefetch thread exited")
+        with obs.span(obs.DATA_WAIT):
+            while True:
+                try:
+                    kind, item = self._q.get(timeout=0.1)
+                    break
+                except queue.Empty:
+                    if self._stop.is_set():
+                        raise StopIteration("prefetcher closed")
+                    if not any(t.is_alive() for t in self._threads):
+                        # producers exited: already-delivered error
+                        # consumed, or they died before enqueueing —
+                        # surface either way
+                        if self._error is not None:
+                            raise RuntimeError("data prefetch thread "
+                                               "failed") from self._error
+                        raise StopIteration("prefetch thread exited")
         if kind == "error":
             raise RuntimeError("data prefetch thread failed") from item
         return item
@@ -367,7 +370,8 @@ def _synth_loop(ref, pipe: DataPipeline, host_q: queue.Queue,
     try:
         while not stop.is_set():
             cursor_box[0], cursor_box[1] = epoch, index
-            batch = fetch(epoch, index)
+            with obs.span(obs.DATA_SYNTH):
+                batch = fetch(epoch, index)
             item = ((epoch, index), batch, pipe.next_cursor(epoch, index))
             if not _stop_aware_put(host_q, stop, ("ok", item)):
                 return
@@ -393,7 +397,8 @@ def _xfer_loop(ref, pipe: DataPipeline, host_q: queue.Queue,
                 _stop_aware_put(dev_q, stop, ("error", item))
                 return
             cursor, batch, nxt = item
-            batch = pipe.device_put(batch, shardings)
+            with obs.span(obs.DATA_TRANSFER):
+                batch = pipe.device_put(batch, shardings)
             if not _stop_aware_put(dev_q, stop, ("ok", (cursor, batch,
                                                         nxt))):
                 return

@@ -9,6 +9,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.models import shardctx
 from repro.models.norms import rmsnorm
 from repro.models.params import dense_init, zeros
@@ -141,33 +142,35 @@ def attention_block(p, x, cfg, *, positions, window, cache=None,
         v = shardctx.constrain(v, hints.attn_kv)
 
     t = k.shape[1]
-    if cache is not None and s == 1:
-        # decode: query sits at `cache_index`; valid keys are <= it, within
-        # the sliding window when one is set.
-        k_pos = jnp.arange(t)
-        mask = k_pos <= cache_index
-        mask &= jnp.where(window > 0, (cache_index - k_pos) < window, True)
-        mask = mask[None, None, None, None]                # (1,1,1,1,T)
-        out = sdpa(q, k, v, mask, softcap=cfg.attn_logit_softcap)
-    elif cfg.use_pallas and cfg.attn_logit_softcap == 0.0:
-        # flash kernel: causal/window masks are positional -> in-kernel;
-        # train gradients route through the kernel's custom VJP (Pallas
-        # backward passes), so this is the differentiable hot path. Block
-        # sizes resolve from cfg inside the ops dispatch layer.
-        from repro.kernels.ops import flash_mha
-        out = flash_mha(q, k, v, causal=cfg.causal, window=window, cfg=cfg)
-    elif cfg.attn_impl == "blockwise" and cfg.attn_logit_softcap == 0.0:
-        from repro.models.blockwise import blockwise_attention_qchunked
-        out = blockwise_attention_qchunked(q, k, v, window,
-                                           causal=cfg.causal,
-                                           block_k=cfg.attn_block_k,
-                                           block_q=cfg.attn_block_q)
-    else:
-        q_pos = jnp.arange(s)[None]
-        k_pos = jnp.arange(t)[None]
-        mask = _mask(q_pos, k_pos, causal=cfg.causal,
-                     window=window)[:, None, None]         # (1,1,1,S,T)
-        out = sdpa(q, k, v, mask, softcap=cfg.attn_logit_softcap)
+    # the core alone, without the projections (repro/obs.py)
+    with jax.named_scope(obs.ATTN_CORE):
+        if cache is not None and s == 1:
+            # decode: query sits at `cache_index`; valid keys are <= it, within
+            # the sliding window when one is set.
+            k_pos = jnp.arange(t)
+            mask = k_pos <= cache_index
+            mask &= jnp.where(window > 0, (cache_index - k_pos) < window, True)
+            mask = mask[None, None, None, None]                # (1,1,1,1,T)
+            out = sdpa(q, k, v, mask, softcap=cfg.attn_logit_softcap)
+        elif cfg.use_pallas and cfg.attn_logit_softcap == 0.0:
+            # flash kernel: causal/window masks are positional -> in-kernel;
+            # train gradients route through the kernel's custom VJP (Pallas
+            # backward passes), so this is the differentiable hot path. Block
+            # sizes resolve from cfg inside the ops dispatch layer.
+            from repro.kernels.ops import flash_mha
+            out = flash_mha(q, k, v, causal=cfg.causal, window=window, cfg=cfg)
+        elif cfg.attn_impl == "blockwise" and cfg.attn_logit_softcap == 0.0:
+            from repro.models.blockwise import blockwise_attention_qchunked
+            out = blockwise_attention_qchunked(q, k, v, window,
+                                               causal=cfg.causal,
+                                               block_k=cfg.attn_block_k,
+                                               block_q=cfg.attn_block_q)
+        else:
+            q_pos = jnp.arange(s)[None]
+            k_pos = jnp.arange(t)[None]
+            mask = _mask(q_pos, k_pos, causal=cfg.causal,
+                         window=window)[:, None, None]         # (1,1,1,S,T)
+            out = sdpa(q, k, v, mask, softcap=cfg.attn_logit_softcap)
     if s > 1:
         out = shardctx.constrain(out, hints.attn_seq)
     out = out.reshape(b, s, h * hd) @ p["wo"].astype(x.dtype)
@@ -220,14 +223,16 @@ def mla_block(p, x, cfg, *, positions, cache=None, cache_index=None):
         # ---- absorbed decode: never materialize per-head K/V ----
         w_uk = p["w_uk"].astype(x.dtype).reshape(m.kv_lora_rank, h, nope)
         q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, w_uk)   # (B,1,H,kv_lora)
-        scores = (jnp.einsum("bshr,btr->bhst", q_lat, c_kv,
-                             preferred_element_type=jnp.float32)
-                  + jnp.einsum("bshd,btd->bhst", q_rope, k_rope,
-                               preferred_element_type=jnp.float32)) * scale
-        valid = jnp.arange(t)[None, None, None, :] <= cache_index
-        scores = jnp.where(valid, scores, NEG_INF)
-        w = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-        ctx = jnp.einsum("bhst,btr->bshr", w, c_kv)          # (B,1,H,kv_lora)
+        with jax.named_scope(obs.ATTN_CORE):
+            scores = (jnp.einsum("bshr,btr->bhst", q_lat, c_kv,
+                                 preferred_element_type=jnp.float32)
+                      + jnp.einsum("bshd,btd->bhst", q_rope, k_rope,
+                                   preferred_element_type=jnp.float32)
+                      ) * scale
+            valid = jnp.arange(t)[None, None, None, :] <= cache_index
+            scores = jnp.where(valid, scores, NEG_INF)
+            w = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+            ctx = jnp.einsum("bhst,btr->bshr", w, c_kv)  # (B,1,H,kv_lora)
         w_uv = p["w_uv"].astype(x.dtype).reshape(m.kv_lora_rank, h, vd)
         out = jnp.einsum("bshr,rhd->bshd", ctx, w_uv)
     else:
@@ -238,19 +243,22 @@ def mla_block(p, x, cfg, *, positions, cache=None, cache_index=None):
             [k_nope, jnp.broadcast_to(k_rope[:, :, None],
                                       (b, t, h, rope_d))], -1)
         qfull = jnp.concatenate([q_nope, q_rope], -1)
-        if cfg.use_pallas:
-            from repro.kernels.ops import flash_mha
-            out = flash_mha(qfull, k, v, causal=True, window=0, cfg=cfg)
-        elif cfg.attn_impl == "blockwise":
-            from repro.models.blockwise import blockwise_attention_qchunked
-            out = blockwise_attention_qchunked(qfull, k, v, 0, causal=True,
-                                               block_k=cfg.attn_block_k,
-                                               block_q=cfg.attn_block_q)
-        else:
-            q_pos = jnp.arange(s)[None]
-            k_pos = jnp.arange(t)[None]
-            mask = _mask(q_pos, k_pos, causal=True, window=0)[:, None, None]
-            out = sdpa(qfull, k, v, mask)
+        with jax.named_scope(obs.ATTN_CORE):
+            if cfg.use_pallas:
+                from repro.kernels.ops import flash_mha
+                out = flash_mha(qfull, k, v, causal=True, window=0, cfg=cfg)
+            elif cfg.attn_impl == "blockwise":
+                from repro.models.blockwise import \
+                    blockwise_attention_qchunked
+                out = blockwise_attention_qchunked(
+                    qfull, k, v, 0, causal=True, block_k=cfg.attn_block_k,
+                    block_q=cfg.attn_block_q)
+            else:
+                q_pos = jnp.arange(s)[None]
+                k_pos = jnp.arange(t)[None]
+                mask = _mask(q_pos, k_pos, causal=True,
+                             window=0)[:, None, None]
+                out = sdpa(qfull, k, v, mask)
 
     out = out.reshape(b, s, h * vd) @ p["wo"].astype(x.dtype)
     return out, new_cache
