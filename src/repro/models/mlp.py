@@ -1,6 +1,8 @@
 """Feed-forward blocks: SwiGLU / GeGLU / GELU / squared-ReLU."""
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -24,11 +26,41 @@ def init_mlp(key, d_model, d_ff, act, dtype=jnp.float32):
     return p
 
 
+_GELU_C = math.sqrt(2.0 / math.pi)
+_GELU_A = 0.044715
+
+
+@jax.custom_vjp
+def gelu(u):
+    """tanh-approximate GELU, ``jax.nn.gelu``'s default form.
+
+    The backward keeps only the input u, where autodiff would also keep
+    intermediates such as u**2, the tanh and the cdf, each (..., d_ff) and
+    stacked per layer by the layer scan. The derivative recomputes the tanh
+    from u in fp32."""
+    return jax.nn.gelu(u)
+
+
+def _gelu_fwd(u):
+    return jax.nn.gelu(u), u
+
+
+def _gelu_bwd(u, g):
+    uf = u.astype(jnp.float32)
+    t = jnp.tanh(_GELU_C * (uf + _GELU_A * uf ** 3))
+    d = 0.5 * (1.0 + t) + 0.5 * uf * (1.0 - t * t) * _GELU_C * (
+        1.0 + 3.0 * _GELU_A * uf * uf)
+    return ((g.astype(jnp.float32) * d).astype(u.dtype),)
+
+
+gelu.defvjp(_gelu_fwd, _gelu_bwd)
+
+
 def _act(h, act):
     if act in ("swiglu",):
         return jax.nn.silu(h)
     if act in ("geglu", "gelu"):
-        return jax.nn.gelu(h)
+        return gelu(h)
     if act == "sqrelu":
         return jnp.square(jax.nn.relu(h))
     raise ValueError(act)

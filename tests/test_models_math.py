@@ -139,3 +139,125 @@ def test_mla_latent_cache_is_compressed():
                                           + full_cfg.mla.v_head_dim)
     latent_width = full_cfg.mla.kv_lora_rank + full_cfg.mla.qk_rope_head_dim
     assert full_kv_width / latent_width > 50   # the ~57x saving
+
+
+# ---------------------------------------------------------------------------
+# GELU and LayerNorm custom VJPs: the forward is the plain expression, the
+# backward keeps only what it needs (the input, and LayerNorm's row stats)
+# ---------------------------------------------------------------------------
+
+def _plain_layernorm(x, scale, bias, eps):
+    xf = x.astype(jnp.float32)
+    mu = jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean((xf - mu) ** 2, axis=-1, keepdims=True)
+    out = (xf - mu) / jnp.sqrt(var + eps)
+    return (out * scale.astype(jnp.float32)
+            + bias.astype(jnp.float32)).astype(x.dtype)
+
+
+def _ln_args(dtype, shape=(3, 5, 64)):
+    ks = jax.random.split(KEY, 3)
+    x = (2.0 * jax.random.normal(ks[0], shape) + 0.5).astype(dtype)
+    scale = 1.0 + 0.1 * jax.random.normal(ks[1], shape[-1:])
+    bias = 0.1 * jax.random.normal(ks[2], shape[-1:])
+    return x, scale, bias
+
+
+def _rel(a, b):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+
+def _plain_and_custom(name):
+    from repro.models.mlp import gelu
+    from repro.models.norms import layernorm
+    if name == "layernorm":
+        return (lambda x, s, b: _plain_layernorm(x, s, b, 1e-6),
+                lambda x, s, b: layernorm(x, s, b, 1e-6))
+    return lambda x, s, b: jax.nn.gelu(x), lambda x, s, b: gelu(x)
+
+
+def _bits(a):
+    return np.asarray(a).tobytes()
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "fp32"])
+@pytest.mark.parametrize("name", ["layernorm", "gelu"])
+def test_custom_vjp_forward_bit_equal(name, dtype):
+    plain, custom = _plain_and_custom(name)
+    args = _ln_args(dtype)
+    got, want = jax.jit(custom)(*args), jax.jit(plain)(*args)
+    assert got.dtype == want.dtype == dtype
+    assert _bits(got) == _bits(want)
+    # a differentiated call computes the same forward
+    total = lambda f: lambda *a: f(*a).astype(jnp.float32).sum()
+    value, _ = jax.jit(jax.value_and_grad(total(custom), (0, 1, 2)))(*args)
+    assert _bits(value) == _bits(jax.jit(total(plain))(*args))
+
+
+@pytest.mark.parametrize("name", ["layernorm", "gelu"])
+def test_custom_vjp_grads_match_finite_differences(name):
+    from jax.test_util import check_grads
+    _, custom = _plain_and_custom(name)
+    check_grads(custom, _ln_args(jnp.float32, shape=(4, 32)), order=1,
+                modes=["rev"])
+
+
+def _grad_gaps(loss_lo, loss_f32, args_lo, args_f32, argnums):
+    """Per leaf, |low-precision grad - fp32 autodiff grad| / |fp32 grad|,
+    for the custom VJPs and for plain autodiff at the same inputs."""
+    from repro.models import mlp as mlp_mod
+    from repro.models import transformer as tmod
+    with jax.default_matmul_precision("highest"), \
+            pytest.MonkeyPatch.context() as mp:
+        mp.setattr(mlp_mod, "gelu", jax.nn.gelu)
+        mp.setattr(tmod, "layernorm", _plain_layernorm)
+        ref = jax.grad(loss_f32, argnums)(*args_f32)
+        autodiff = jax.grad(loss_lo, argnums)(*args_lo)
+    custom = jax.grad(loss_lo, argnums)(*args_lo)
+    ref = jax.tree.leaves(ref)
+
+    def gaps(g):
+        return np.array([_rel(a, b) for a, b in zip(jax.tree.leaves(g), ref)])
+
+    return gaps(custom), gaps(autodiff)
+
+
+@pytest.mark.parametrize("act", ["gelu", "geglu"])
+def test_mlp_bf16_grads_close_to_fp32_autodiff(act):
+    from repro.models.mlp import init_mlp, mlp
+    ks = jax.random.split(KEY, 3)
+    p = init_mlp(ks[0], 64, 256, act)
+    p = {k: v + 0.02 * jax.random.normal(ks[1], v.shape) for k, v in p.items()}
+    x = jax.random.normal(ks[2], (4, 16, 64))
+    w = jnp.cos(jnp.arange(64.0))
+
+    def loss(p, x):
+        return jnp.sum(mlp(p, x, act).astype(jnp.float32) * w)
+
+    custom, autodiff = _grad_gaps(loss, loss, (p, x.astype(jnp.bfloat16)),
+                                  (p, x), (0, 1))
+    assert custom.max() < 3e-2, (custom, autodiff)
+    assert np.all(custom <= 1.5 * autodiff + 1e-3), (custom, autodiff)
+
+
+def test_vit_b16_bf16_grads_close_to_fp32_autodiff():
+    """Two full-width vit-b16 layers at 64 px in bf16: the custom VJPs lose
+    nothing against plain autodiff, both read against fp32 HIGHEST."""
+    from repro.configs import get_config
+    from repro.models import transformer as model
+    cfg = get_config("vit-b16").replace(num_layers=2, image_size=64)
+    params = model.init_params(cfg, KEY)
+    batch = {"images": jax.random.normal(KEY, (4, 64, 64, 3)),
+             "labels": jnp.arange(4) % cfg.num_classes}
+
+    def loss_for(c):
+        return lambda p: model.loss_fn(c, p, batch)[0]
+
+    assert cfg.dtype == "bfloat16"
+    custom, autodiff = _grad_gaps(
+        loss_for(cfg), loss_for(cfg.replace(dtype="float32")),
+        (params,), (params,), 0)
+    assert custom.max() < 3e-2, (custom, autodiff)
+    assert np.all(custom <= 1.5 * autodiff + 1e-3), (custom, autodiff)

@@ -5,11 +5,15 @@ described, not attached. Interpret mode (every other kernel test) checks
 the math but not Mosaic's tiling and layout rules; these compiles do, at
 the real widths: vit-b16 attention (S=197, H=12, D=64, bf16) with the
 config's tiles, rmsnorm at d_model 768, and wkv6 at rwkv6-7b's heads.
+The vit-b16 gradient is compiled whole to read what its layer scan keeps
+for the backward.
 
 The topology is described inside a module fixture, never at import: only
 one process may load libtpu at a time, and pytest-xdist workers import
 every test file. Keep all such compiles in this one file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -20,6 +24,7 @@ from repro.kernels import vjp
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.rmsnorm import fused_rmsnorm
 from repro.kernels.wkv6 import wkv6_chunked_kernel
+from repro.models import transformer as model
 
 
 @pytest.fixture(scope="module")
@@ -108,3 +113,43 @@ def test_kernel_compiles_for_v5e(kernel, grad, one_chip,
     compiled = jax.jit(fn).lower(*shapes).compile()
     # the Mosaic kernel is in the program (no interpret-mode fallback)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _forward_scan_stacks(hlo_text, layers):
+    """(dtype, shape) of every per-layer stack the forward layer scan
+    carries: the shapes with a leading ``layers`` axis in the tuple of the
+    one ``while`` whose op_name is the forward's (jvp, not transposed)."""
+    loops = []
+    for line in hlo_text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?\S+ = \((.*?)\) while\(", line)
+        name = re.search(r'op_name="([^"]*)"', line)
+        if m and name and "jvp(" in name.group(1) \
+                and "transpose(" not in name.group(1):
+            loops.append(m.group(1))
+    assert len(loops) == 1, loops
+    return [(dt, tuple(int(d) for d in dims.split(",")))
+            for dt, dims in re.findall(r"(\w+)\[([\d,]+)\]", loops[0])
+            if dims.startswith(f"{layers},")]
+
+
+def test_vit_b16_layer_scan_keeps_only_needed_residuals(one_chip,
+                                                        no_persistent_cache):
+    """GELU keeps its input and LayerNorm its input and row stats: no fp32
+    (L, B, S, d_model) stack, and of (L, B, S, d_ff) only the GELU input
+    and output (autodiff alone keeps six of those and six fp32 rows)."""
+    cfg = get_config("vit-b16")
+    b, px, layers = 8, cfg.image_size, cfg.num_layers
+    s = (px // cfg.patch_size) ** 2 + 1
+    params = jax.eval_shape(lambda: model.init_params(cfg, jax.random.key(0)))
+    spec = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                  sharding=one_chip)
+    args = (jax.tree.map(lambda a: spec(a.shape, a.dtype), params),
+            {"images": spec((b, px, px, 3), jnp.bfloat16),
+             "labels": spec((b,), jnp.int32)})
+    grad = jax.jit(jax.grad(lambda p, x: model.loss_fn(cfg, p, x)[0]))
+    stacks = _forward_scan_stacks(grad.lower(*args).compile().as_text(),
+                                  layers)
+    rows = [(dt, sh[-1]) for dt, sh in stacks if sh[1:3] == (b, s)]
+    assert rows, stacks
+    assert ("f32", cfg.d_model) not in rows, stacks
+    assert sum(d == cfg.d_ff for _, d in rows) <= 2, stacks
