@@ -53,7 +53,7 @@ def program_readings(cell, seeds, devices) -> dict:
     import system
     out = {}
     for seed in seeds:
-        t = system.build(cell.config, cell.traffic, seed, devices)
+        t = system.build(cell, seed, devices)
         try:
             out[seed] = run.check_steps(t, cell.traffic)
         finally:
@@ -64,16 +64,15 @@ def program_readings(cell, seeds, devices) -> dict:
 def readings(cell, seeds, control_seeds, devices) -> dict:
     from reference import Reference
     prog = program_readings(cell, seeds, devices)
-    ref = Reference(cell.config, cell.traffic, device=devices[0])
-    fp8 = Reference(cell.config, cell.traffic, precision="fp8",
-                    device=devices[0])
+    ref = Reference(cell, device=devices[0])
+    fp8 = Reference(cell, precision="fp8", device=devices[0])
     batch = cell.traffic["global_batch"]
     faults = {"half_batch": {"rows": batch // 2},
               "altered_logits": {"logit_shift": LOGIT_SHIFT}}
     if cell.chips > 1:
         faults["no_exchange"] = {"rows": batch // cell.chips}
     opt = cell.traffic["optimizer"]
-    planted = {name: Reference(cell.config, cell.traffic, device=devices[0],
+    planted = {name: Reference(cell, device=devices[0],
                                step_opt=dict(opt, **over))
                for name, over in OPTIMIZER_FAULTS.items()}
     out = {"program": {}, "control": {},

@@ -1,17 +1,12 @@
 """Plain float32 reference of a cell's training steps.
 
-ViT (Dosovitskiy et al. 2021) written out in ``jax.numpy`` from the
-configuration file's shapes and the workload file's recipe, with no kernel,
-no sharding and nothing imported from the program: the on-device
-preprocessing (nearest upsample from the native grid, per-channel
-normalisation), patch embedding, class token and position table, the
-pre-LayerNorm encoder blocks (multi-head attention, tanh-GELU MLP), the
-final LayerNorm, the linear head on the class token, mean cross-entropy,
-the global-norm gradient clip and AdamW with its warm-up/cosine schedule.
-
-Weights are drawn from the seed by the configuration's initialiser: the
-same key splits and truncated-normal draws, so that the reference starts
-where the program starts without taking anything the program made.
+The cell's model family (``families/<family>.py``) gives the model: its
+weights drawn from the seed as the program draws its own, its batches made
+again from the seed, and its loss written out in ``jax.numpy`` with no
+kernel, no sharding and nothing imported from the program. This module
+gives what is the same for every family: the mean of that loss over the
+batch, the global-norm gradient clip, AdamW with its warm-up/cosine
+schedule, and the readings the check compares.
 
 Every matrix product runs at ``Precision.HIGHEST`` in float32. The control
 (``precision="fp8"``) rounds both operands of every product to float8
@@ -32,8 +27,6 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-import traffic as traffic_mod
 
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -75,119 +68,6 @@ def _einsum_fp8_bwd(eq, res, g):
 _einsum_fp8.defvjp(_einsum_fp8_fwd, _einsum_fp8_bwd)
 
 PRODUCTS = {"float32": _einsum, "fp8": _einsum_fp8}
-
-
-# ---------------------------------------------------------------------------
-# weights
-# ---------------------------------------------------------------------------
-
-def _dense(key, shape):
-    """Truncated normal on [-2, 2], std 1/sqrt(fan in)."""
-    return (1.0 / math.sqrt(shape[-2])) * jax.random.truncated_normal(
-        key, -2.0, 2.0, shape, F32)
-
-
-def _norm(d):
-    return {"scale": jnp.ones((d,), F32), "bias": jnp.zeros((d,), F32)}
-
-
-def init_params(config: dict, key):
-    d, dff, L = config["d_model"], config["d_ff"], config["num_layers"]
-    h, hd, ps = config["num_heads"], config["head_dim"], config["patch_size"]
-    n = (config["image_size"] // ps) ** 2
-    keys = jax.random.split(key, 8)
-
-    def layer(key):
-        ka, km = jax.random.split(key, 2)
-        qkvo = jax.random.split(ka, 4)
-        mlp = jax.random.split(km, 3)
-        return {
-            "ln1": _norm(d), "ln2": _norm(d),
-            "attn": {"wq": _dense(qkvo[0], (d, h * hd)),
-                     "wk": _dense(qkvo[1], (d, h * hd)),
-                     "wv": _dense(qkvo[2], (d, h * hd)),
-                     "wo": _dense(qkvo[3], (h * hd, d))},
-            "mlp": {"w_out": _dense(mlp[2], (dff, d)),
-                    "w_up": _dense(mlp[1], (d, dff)),
-                    "b_up": jnp.zeros((dff,), F32),
-                    "b_out": jnp.zeros((d,), F32)},
-        }
-
-    return {
-        "embed": {
-            "patch_w": _dense(keys[0], (ps * ps * 3, d)),
-            "patch_b": jnp.zeros((d,), F32),
-            "cls": jnp.zeros((1, 1, d), F32),
-            "pos": 0.02 * jax.random.truncated_normal(
-                keys[5], -2.0, 2.0, (n + 1, d), F32),
-        },
-        "stack": jax.vmap(layer)(jax.random.split(keys[1], L)),
-        "final_norm": _norm(d),
-        "head": {"w": _dense(keys[3], (d, config["num_classes"])),
-                 "b": jnp.zeros((config["num_classes"],), F32)},
-    }
-
-
-# ---------------------------------------------------------------------------
-# forward and loss
-# ---------------------------------------------------------------------------
-
-def _layernorm(x, p, eps):
-    mu = jnp.mean(x, -1, keepdims=True)
-    var = jnp.mean(jnp.square(x - mu), -1, keepdims=True)
-    return (x - mu) / jnp.sqrt(var + eps) * p["scale"] + p["bias"]
-
-
-def _gelu(x):
-    return 0.5 * x * (1.0 + jnp.tanh(
-        math.sqrt(2.0 / math.pi) * (x + 0.044715 * x ** 3)))
-
-
-def images_in(config: dict, dataset: str, u8):
-    """uint8 (B, 32, 32, 3) -> normalised float32 at the model's size."""
-    ds = traffic_mod.DATASETS[dataset]
-    k = config["image_size"] // ds["native"]
-    x = jnp.repeat(jnp.repeat(u8.astype(F32), k, axis=1), k, axis=2)
-    return (x / 255.0 - jnp.asarray(ds["mean"], F32)) \
-        / jnp.asarray(ds["std"], F32)
-
-
-def nll_sum(config: dict, dataset: str, mm, params, u8, labels, shift):
-    """Sum over the rows of -log p(label); ``shift`` is added to the
-    logits (zero, except where a fault is planted)."""
-    d, h, hd = config["d_model"], config["num_heads"], config["head_dim"]
-    ps, eps = config["patch_size"], config["norm_eps"]
-    x = images_in(config, dataset, u8)
-    b, n = x.shape[0], config["image_size"] // ps
-    patches = x.reshape(b, n, ps, n, ps, 3).transpose(0, 1, 3, 2, 4, 5) \
-        .reshape(b, n * n, ps * ps * 3)
-    e = params["embed"]
-    t = mm("bpk,kd->bpd", patches, e["patch_w"]) + e["patch_b"]
-    t = jnp.concatenate([jnp.broadcast_to(e["cls"], (b, 1, d)), t], 1) \
-        + e["pos"][None]
-    s = t.shape[1]
-
-    def block(t, lp):
-        a = _layernorm(t, lp["ln1"], eps)
-        at = lp["attn"]
-        q = mm("bsd,de->bse", a, at["wq"]).reshape(b, s, h, hd)
-        k = mm("bsd,de->bse", a, at["wk"]).reshape(b, s, h, hd)
-        v = mm("bsd,de->bse", a, at["wv"]).reshape(b, s, h, hd)
-        scores = mm("bshd,bthd->bhst", q, k) / math.sqrt(hd)
-        w = jax.nn.softmax(scores, axis=-1)
-        o = mm("bhst,bthd->bshd", w, v).reshape(b, s, h * hd)
-        t = t + mm("bse,ed->bsd", o, at["wo"])
-        m = _layernorm(t, lp["ln2"], eps)
-        ml = lp["mlp"]
-        u = _gelu(mm("bsd,df->bsf", m, ml["w_up"]) + ml["b_up"])
-        return t + mm("bsf,fd->bsd", u, ml["w_out"]) + ml["b_out"], None
-
-    t, _ = jax.lax.scan(jax.checkpoint(block), t, params["stack"])
-    cls = _layernorm(t[:, 0], params["final_norm"], eps)
-    logits = mm("bd,dc->bc", cls, params["head"]["w"]) \
-        + params["head"]["b"] + shift
-    gold = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
-    return jnp.sum(jax.nn.logsumexp(logits, axis=-1) - gold)
 
 
 # ---------------------------------------------------------------------------
@@ -257,18 +137,21 @@ class Reference:
     first ``check_steps`` batches from the seed's weights and returns what
     the check compares (see ``check.py``)."""
 
-    def __init__(self, config: dict, traffic: dict, precision="float32",
-                 device=None, step_opt=None):
+    def __init__(self, cell, precision="float32", device=None,
+                 step_opt=None):
         """``step_opt``: optimizer settings the steps take instead of the
         workload's (a fault planted); the readings still take the
         workload's."""
-        self.config, self.traffic = config, traffic
-        self.opt = traffic["optimizer"]
+        self.config, self.traffic = cell.config, cell.traffic
+        self.family = cell.family
+        self.opt = self.traffic["optimizer"]
         self.device = device or jax.devices()[0]
         mm = PRODUCTS[precision]
-        loss = functools.partial(nll_sum, config, traffic["dataset"], mm)
+        loss = functools.partial(self.family.nll_sum, self.config,
+                                 self.traffic, mm)
         self._grad = jax.jit(jax.value_and_grad(loss))
-        self._init = jax.jit(functools.partial(init_params, config))
+        self._init = jax.jit(functools.partial(self.family.init_params,
+                                               self.config))
         self._step = jax.jit(functools.partial(_adamw, step_opt or self.opt))
 
     def readings(self, seed: int, *, rows=None, logit_shift=0.0) -> dict:
@@ -276,7 +159,7 @@ class Reference:
         the mean taken over them; ``logit_shift`` is added to the first
         row's first logit. Both plant faults; the defaults are the sound reference."""
         steps, block = self.traffic["check_steps"], self.traffic["ref_rows"]
-        c = self.config["num_classes"]
+        fam = self.family
         with jax.default_device(self.device), \
                 jax.default_matmul_precision("highest"):
             params = self._init(jax.random.PRNGKey(seed))
@@ -285,16 +168,17 @@ class Reference:
             v = jax.tree.map(jnp.zeros_like, params)
             losses = []
             for k in range(steps):
-                u8, labels = traffic_mod.batch(self.traffic, seed, k)
-                n = rows or len(labels)
+                arrays = fam.batch(self.config, self.traffic, seed, k)
+                n = rows or len(arrays[0])
                 total, grads = 0.0, None
                 for lo in range(0, n, block):
                     hi = min(lo + block, n)
-                    shift = np.zeros((hi - lo, c), np.float32)
+                    shift = np.zeros(fam.logits_shape(
+                        self.config, self.traffic, hi - lo), np.float32)
                     if lo == 0:
-                        shift[0, 0] += logit_shift
-                    val, g = self._grad(params, u8[lo:hi], labels[lo:hi],
-                                        shift)
+                        shift[(0,) * shift.ndim] += logit_shift
+                    val, g = self._grad(params, *(a[lo:hi] for a in arrays),
+                                        shift=shift)
                     total = total + val
                     grads = g if grads is None else \
                         jax.tree.map(jnp.add, grads, g)

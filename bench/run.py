@@ -1,4 +1,4 @@
-"""Benchmark of the vit-b16 trainer on TPU: one cell, one run.
+"""Benchmark of the trainer on TPU: one cell, one run.
 
     python bench/run.py --workload vit-b16.dp1 --seed 7 --seconds 25 --trace 0
 
@@ -189,7 +189,7 @@ def run(cell: cells.Cell, seed: int, seconds: float, trace: bool, devices,
     tr = cell.traffic
     counter = CompileCounter()
     t_build = time.perf_counter()
-    t = system.build(cell.config, tr, seed, devices)
+    t = system.build(cell, seed, devices)
     t_steps = time.perf_counter()
     prog = check_steps(t, tr)
     setup_s = time.perf_counter() - T_START
@@ -239,7 +239,7 @@ def run(cell: cells.Cell, seed: int, seconds: float, trace: bool, devices,
     del t, compiled
     gc.collect()
 
-    ref = Reference(cell.config, tr, device=devices[0]).readings(seed)
+    ref = Reference(cell, device=devices[0]).readings(seed)
     checks, correct = check.judge(check.gaps(prog, ref), tr["limits"])
     print(f"[bench] grad_gap from the first moment "
           f"{check.leaf_gap(prog['grad'], ref['grad'])!r}, from the second "

@@ -255,7 +255,7 @@ def main(argv=None):
     run.use_compile_cache()
     devices = run.chip_devices(cell.chips, table)
     tr = cell.traffic
-    t = system.build(cell.config, tr, args.seed, devices)
+    t = system.build(cell, args.seed, devices)
     run.check_steps(t, tr)
     for _ in range(WARM_STEPS):
         system.train_step(t)

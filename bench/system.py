@@ -3,7 +3,8 @@ the per-step calls its loop makes at its defaults.
 
 ``launch/train.py`` runs a fixed ``--steps`` and cannot be handed a
 deadline, so this module repeats its construction and its loop body call
-for call: ``get_config`` -> ``make_source`` -> ``DataPipeline`` ->
+for call: ``get_config`` -> the data path (the family's ``build_data``:
+``make_source`` -> ``DataPipeline`` for a dataset job) ->
 ``make_local_mesh`` -> ``EngineConfig`` -> ``DistributedEngine`` ->
 ``init_state(seed)`` -> ``jit_train_step()``, the ``Prefetcher`` over
 ``batch_specs`` shardings, and per step ``next(prefetcher)``, the fault
@@ -14,20 +15,13 @@ host was doing in an idle gap.
 """
 from __future__ import annotations
 
+import dataclasses
 import time
 from dataclasses import dataclass, field
 from typing import Any
 
 INPUT_WAIT, DISPATCH, GUARD_READ = "input_wait", "dispatch", "guard_read"
 HOST_SPANS = (INPUT_WAIT, DISPATCH, GUARD_READ)
-
-# the shape keys of a configuration file that must equal the program's
-# ModelConfig, so that a registry change shows as a refusal, not as a
-# silently different model
-SHAPE_KEYS = ("num_layers", "d_model", "num_heads", "num_kv_heads",
-              "head_dim", "d_ff", "image_size", "patch_size", "num_classes",
-              "norm_eps", "act", "dtype", "param_dtype", "use_pallas",
-              "attn_impl", "remat", "qkv_bias")
 
 
 @dataclass
@@ -47,35 +41,42 @@ class Trainer:
         self.state = None
 
 
-def model_config(config: dict):
+def _at(obj, key: str):
+    """``obj``'s value at a dotted key, through dicts or attributes."""
+    for part in key.split("."):
+        obj = obj[part] if isinstance(obj, dict) else getattr(obj, part)
+    return obj
+
+
+def model_config(config: dict, shape_keys):
     """The program's ModelConfig for a configuration file, checked
-    against the file's published shapes."""
+    against the file's published shapes at each of ``shape_keys``, so that
+    a registry change shows as a refusal, not as a silently different
+    model. An override that is a dict replaces fields of a nested group."""
     from repro.configs import get_config
-    cfg = get_config(config["arch"]).replace(**config.get("overrides", {}))
-    want = {k: config[k] for k in SHAPE_KEYS if k in config}
-    got = {k: getattr(cfg, k) for k in want}
+    cfg = get_config(config["arch"])
+    cfg = cfg.replace(**{
+        k: dataclasses.replace(getattr(cfg, k), **v) if isinstance(v, dict)
+        else v for k, v in config.get("overrides", {}).items()})
+    want = {k: _at(config, k) for k in shape_keys}
+    got = {k: _at(cfg, k) for k in shape_keys}
     if got != want:
         raise ValueError(f"the program's {config['arch']} config differs from "
                          f"{config['name']}.json: program {got}, file {want}")
     return cfg
 
 
-def build(config: dict, traffic: dict, seed: int, devices) -> Trainer:
-    """Everything ``launch/train.py:main`` builds for this job, with the
-    state initialised on the device from ``seed``."""
+def build(cell, seed: int, devices) -> Trainer:
+    """Everything ``launch/train.py:main`` builds for this cell's job, with
+    the state initialised on the device from ``seed``."""
     from repro.configs import EngineConfig
     from repro.core import sharding as shd
     from repro.core.engine import DistributedEngine
-    from repro.data import DataPipeline, make_source
     from repro.launch.mesh import make_local_mesh
 
-    cfg = model_config(config)
-    source = make_source(traffic["dataset"], seed=seed,
-                         resolution=cfg.image_size,
-                         train_size=traffic["train_size"])
-    if source.spec.num_classes != cfg.num_classes:
-        raise ValueError(f"{traffic['dataset']} has {source.spec.num_classes} "
-                         f"classes, the config {cfg.num_classes}")
+    traffic = cell.traffic
+    cfg = model_config(cell.config, cell.family.SHAPE_KEYS)
+    pipe, preproc = cell.family.build_data(cfg, traffic, seed)
     mesh = make_local_mesh(devices=devices)
     opt = traffic["optimizer"]
     ecfg = EngineConfig(
@@ -86,9 +87,7 @@ def build(config: dict, traffic: dict, seed: int, devices) -> Trainer:
         lr_schedule=opt["schedule"], total_steps=opt["total_steps"],
         warmup_steps=opt["warmup_steps"], seed=seed,
         guard_anomalies=traffic["guard"])
-    eng = DistributedEngine(cfg, ecfg, mesh, preproc=source.preproc)
-    pipe = DataPipeline(kind="image", global_batch=traffic["global_batch"],
-                        source=source, seed=seed)
+    eng = DistributedEngine(cfg, ecfg, mesh, preproc=preproc)
     state = eng.init_state(seed=seed)
     step_fn = eng.jit_train_step()
     bshard = shd.named(mesh, shd.batch_specs(cfg, pipe.batch_shapes(), mesh))

@@ -29,8 +29,8 @@ def main():
     out = Path(sys.argv[1]) if len(sys.argv) > 1 else HERE / "data"
     out.mkdir(parents=True, exist_ok=True)
     devices = run.chip_devices(4, run.peaks())
-    cell = small.cell("vit-b16.dp4-zero0", chips=4, global_batch=16)
-    t = system.build(cell.config, cell.traffic, 1, devices)
+    cell = small.cell("vit", chips=4, global_batch=16)
+    t = system.build(cell, 1, devices)
     for _ in range(3):
         system.train_step(t)
     with tempfile.TemporaryDirectory() as d:

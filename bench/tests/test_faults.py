@@ -1,7 +1,8 @@
 """A run with the timed path broken underneath comes out not correct, and
 so does the control; a sound run comes out correct. CPU, small widths,
-the harness's look for a chip skipped (``run.run`` is handed the
-devices)."""
+each model family at its ``SMALL`` cut and limits (set between that cut's
+sound readings and its control's and faults'), the harness's look for a
+chip skipped (``run.run`` is handed the devices)."""
 import jax
 import jax.numpy as jnp
 import pytest
@@ -13,23 +14,17 @@ from reference import Reference
 
 import small
 
-# between the small cell's sound readings and its control's and faults'
-# (calibrate.readings at these widths on the CPU: sound at most 1.5e-3,
-# 6.2e-3, 4.9e-3; the control at least 1.1e-2, 2.9e-2, 2.6e-2)
-LIMITS = {"loss_gap": 5e-3, "grad_gap": 2e-2, "update_gap": 2e-2}
 PEAK = {"bf16_flops_per_s": 1e12}
-
-
-def small_cell(workload="vit-b16.dp1", chips=1, **over):
-    return small.cell(workload, chips=chips, limits=LIMITS, **over)
+FAMILIES = pytest.mark.parametrize("family", small.families())
 
 
 def result(cell, seed=2 ** 31 + 3):
     return run.run(cell, seed, 0.5, False, jax.devices()[:cell.chips], PEAK)
 
 
-def test_sound_run_is_correct():
-    res = result(small_cell())
+@FAMILIES
+def test_sound_run_is_correct(family):
+    res = result(small.cell(family))
     assert res["correct"], res["checks"]
     assert res["attempted"] > 0 and res["failed"] == 0
     assert list(res)[-1] == "checks"
@@ -43,45 +38,54 @@ def _patch_step(monkeypatch, wrap):
                                                         batch))
 
 
-def _rows(batch, n):
+def _rows(batch, part):
+    """The first ``1/part`` of the batch's rows."""
+    n = len(next(iter(batch.values()))) // part
     return {k: v[:n] for k, v in batch.items()}
 
 
-def test_state_left_unchanged(monkeypatch):
+@FAMILIES
+def test_state_left_unchanged(monkeypatch, family):
     _patch_step(monkeypatch, lambda f, s, st, b: (st, f(s, st, b)[1]))
-    res = result(small_cell())
+    res = result(small.cell(family))
     assert not res["correct"]
     assert res["checks"]["update_gap"]["value"] == pytest.approx(1.0)
 
 
-def test_half_the_batch_left_out(monkeypatch):
-    _patch_step(monkeypatch, lambda f, s, st, b: f(
-        s, st, _rows(b, b["labels"].shape[0] // 2)))
-    assert not result(small_cell())["correct"]
+@FAMILIES
+def test_half_the_batch_left_out(monkeypatch, family):
+    _patch_step(monkeypatch, lambda f, s, st, b: f(s, st, _rows(b, 2)))
+    assert not result(small.cell(family))["correct"]
 
 
-def test_exchange_between_chips_left_out(monkeypatch):
+@pytest.mark.parametrize("family", small.families(chips=4))
+def test_exchange_between_chips_left_out(monkeypatch, family):
     # each chip updating from its own shard is what chip 0 keeps
-    _patch_step(monkeypatch, lambda f, s, st, b: f(
-        s, st, _rows(b, b["labels"].shape[0] // 4)))
-    cell = small_cell("vit-b16.dp4-zero0", chips=4, global_batch=16)
+    _patch_step(monkeypatch, lambda f, s, st, b: f(s, st, _rows(b, 4)))
+    cell = small.cell(family, chips=4, global_batch=16)
     assert not result(cell)["correct"]
 
 
-def test_answer_altered_where_produced(monkeypatch):
+@FAMILIES
+def test_answer_altered_where_produced(monkeypatch, family):
     from repro.models import transformer
     head = transformer._head
-    monkeypatch.setattr(transformer, "_head", lambda cfg, p, h: head(
-        cfg, p, h).at[0, 0].add(jnp.asarray(calibrate.LOGIT_SHIFT, h.dtype)))
-    assert not result(small_cell())["correct"]
+
+    def altered(cfg, p, h):
+        out = head(cfg, p, h)
+        return out.at[(0,) * out.ndim].add(
+            jnp.asarray(calibrate.LOGIT_SHIFT, h.dtype))
+    monkeypatch.setattr(transformer, "_head", altered)
+    assert not result(small.cell(family))["correct"]
 
 
-def test_wrong_second_moment_decay(monkeypatch):
+@FAMILIES
+def test_wrong_second_moment_decay(monkeypatch, family):
     from repro.core import engine
     make = engine.make_optimizer
     monkeypatch.setattr(engine, "make_optimizer",
                         lambda *a, **kw: make(*a, **dict(kw, b2=0.999)))
-    res = result(small_cell())
+    res = result(small.cell(family))
     assert not res["correct"]
     assert res["checks"]["grad_gap"]["value"] > 0.5
 
@@ -95,9 +99,10 @@ def test_only_numbers_with_a_limit_are_compared():
         check.judge({}, {"step_gap": 1.0})
 
 
-def test_control_is_not_correct():
-    cell = small_cell()
-    ref = Reference(cell.config, cell.traffic).readings(7)
-    fp8 = Reference(cell.config, cell.traffic, precision="fp8").readings(7)
-    _, ok = check.judge(check.gaps(fp8, ref), LIMITS)
+@FAMILIES
+def test_control_is_not_correct(family):
+    cell = small.cell(family)
+    ref = Reference(cell).readings(7)
+    fp8 = Reference(cell, precision="fp8").readings(7)
+    _, ok = check.judge(check.gaps(fp8, ref), cell.traffic["limits"])
     assert not ok
