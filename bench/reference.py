@@ -16,8 +16,16 @@ configuration's bfloat16 that a later change could be tempted to take, and
 the check has to refuse it.
 
 A batch is processed in blocks of ``ref_rows`` rows, each layer
-recomputed in the backward pass, so that the reference fits on one chip
-next to nothing else.
+recomputed in the backward pass, and the device holds at most three float32
+copies of the parameters (12 B a parameter) besides one leaf and a block's
+activations: the parameters, one gradient accumulator, and the current
+block's gradient or, in the update, one leaf's two moments. The first
+weights and Adam's moments stay in host memory; the update runs leaf by
+leaf, in place, and each leaf's change is taken against its first weights
+uploaded alone. ``tests/test_reference.py`` holds the reference to that
+bound on the CPU, after every jitted call. For a model of 0.7e9
+parameters it comes to 8.7 GB of the 16 GB of one v5e, beside a block's
+activations (``tests/dense_lm.py`` runs that model on the chip).
 """
 from __future__ import annotations
 
@@ -85,46 +93,80 @@ def learning_rate(opt: dict, step: int) -> float:
     return lr * (f + (1 - f) * 0.5 * (1 + math.cos(math.pi * frac)))
 
 
-def _adamw(opt, params, m, v, grads, step, lr):
-    """Clip by global norm, then one AdamW step (decoupled decay)."""
+def _clip_scale(opt, grads):
+    """The global-norm clip's factor for the whole gradient tree."""
     leaves = jax.tree.leaves(grads)
     norm = jnp.sqrt(sum(jnp.sum(jnp.square(g)) for g in leaves))
-    grads = jax.tree.map(
-        lambda g: g * jnp.minimum(1.0, opt["grad_clip"] / (norm + 1e-9)),
-        grads)
+    return jnp.minimum(1.0, opt["grad_clip"] / (norm + 1e-9))
+
+
+def _adamw_leaf(opt, p, m, v, g, scale, step, lr):
+    """One AdamW step (decoupled decay) of one leaf, its gradient clipped
+    by ``scale``; returns the new leaf, its moments and the clipped
+    gradient."""
+    g = g * scale
     b1, b2, eps, wd = opt["b1"], opt["b2"], opt["eps"], opt["weight_decay"]
-    m = jax.tree.map(lambda m, g: b1 * m + (1 - b1) * g, m, grads)
-    v = jax.tree.map(lambda v, g: b2 * v + (1 - b2) * g * g, v, grads)
+    m = b1 * m + (1 - b1) * g
+    v = b2 * v + (1 - b2) * g * g
     bc1 = 1 - b1 ** (step + 1.0)
     bc2 = 1 - b2 ** (step + 1.0)
-    params = jax.tree.map(
-        lambda p, m, v: p - lr * (m / bc1 / (jnp.sqrt(v / bc2) + eps)
-                                  + wd * p), params, m, v)
-    return params, m, v, grads
+    p = p - lr * (m / bc1 / (jnp.sqrt(v / bc2) + eps) + wd * p)
+    return p, m, v, g
+
+
+# the accumulator is updated in place: the device never holds two
+@functools.partial(jax.jit, donate_argnums=0)
+def _add(acc, g):
+    return jax.tree.map(jnp.add, acc, g)
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _mean(acc, n):
+    return jax.tree.map(lambda g: g / n, acc)
+
+
+def _norm(x):
+    return jnp.sqrt(jnp.sum(jnp.square(x.astype(F32))))
 
 
 @jax.jit
 def _norms(xs):
-    return [jnp.sqrt(jnp.sum(jnp.square(x.astype(F32)))) for x in xs]
+    return [_norm(x) for x in xs]
 
 
-def leaf_norms(tree) -> dict:
-    """{key path: float32 norm} of every leaf."""
+@jax.jit
+def _root_norms(xs):
+    # the square root is taken inside the reduction: no rooted copy
+    return [_norm(jnp.sqrt(x)) for x in xs]
+
+
+@jax.jit
+def _change_norm(p, p0):
+    return _norm(p - p0)
+
+
+def leaf_norms(tree, root=False) -> dict:
+    """{key path: float32 norm} of every leaf, or of its square root."""
     flat = jax.tree_util.tree_flatten_with_path(tree)[0]
-    norms = _norms([x for _, x in flat])
+    norms = (_root_norms if root else _norms)([x for _, x in flat])
     return {jax.tree_util.keystr(p): float(n) for (p, _), n in
             zip(flat, norms)}
+
+
+def grad2_norm(root_nu_norm: float, opt: dict) -> float:
+    """The norm of the gradient that one AdamW step from zero moments
+    took, read from the norm of its second moment's square root:
+    ``sqrt(nu / (1 - b2))``, which a wrong ``b2`` scales."""
+    return root_nu_norm / math.sqrt(1 - opt["b2"])
 
 
 def grad_norms_from_moments(mu, nu, opt: dict) -> tuple:
     """Per-leaf norms of the gradient that one AdamW step from zero
     moments took, read back from its first moment (``mu / (1 - b1)``) and
-    from its second (``sqrt(nu / (1 - b2))``, which a wrong ``b2``
-    scales)."""
-    b1, b2 = opt["b1"], opt["b2"]
-    g1 = {k: v / (1 - b1) for k, v in leaf_norms(mu).items()}
-    g2 = {k: v / math.sqrt(1 - b2)
-          for k, v in leaf_norms(jax.tree.map(jnp.sqrt, nu)).items()}
+    from its second (``grad2_norm``)."""
+    g1 = {k: v / (1 - opt["b1"]) for k, v in leaf_norms(mu).items()}
+    g2 = {k: grad2_norm(v, opt)
+          for k, v in leaf_norms(nu, root=True).items()}
     return g1, g2
 
 
@@ -152,7 +194,10 @@ class Reference:
         self._grad = jax.jit(jax.value_and_grad(loss))
         self._init = jax.jit(functools.partial(self.family.init_params,
                                                self.config))
-        self._step = jax.jit(functools.partial(_adamw, step_opt or self.opt))
+        opt = step_opt or self.opt
+        self._clip = jax.jit(functools.partial(_clip_scale, opt))
+        self._step = jax.jit(functools.partial(_adamw_leaf, opt),
+                             donate_argnums=(0, 1, 2, 3))
 
     def readings(self, seed: int, *, rows=None, logit_shift=0.0) -> dict:
         """``rows``: train on the first ``rows`` rows of each batch only,
@@ -163,10 +208,14 @@ class Reference:
         with jax.default_device(self.device), \
                 jax.default_matmul_precision("highest"):
             params = self._init(jax.random.PRNGKey(seed))
-            p0 = params
-            m = jax.tree.map(jnp.zeros_like, params)
-            v = jax.tree.map(jnp.zeros_like, params)
-            losses = []
+            keys = [jax.tree_util.keystr(p) for p, _ in
+                    jax.tree_util.tree_flatten_with_path(params)[0]]
+            leaves, tree = jax.tree.flatten(params)
+            del params
+            p0 = [np.array(x) for x in leaves]
+            m = [np.zeros_like(x) for x in p0]
+            v = [np.zeros_like(x) for x in p0]
+            losses, grad2 = [], {}
             for k in range(steps):
                 arrays = fam.batch(self.config, self.traffic, seed, k)
                 n = rows or len(arrays[0])
@@ -177,18 +226,29 @@ class Reference:
                         self.config, self.traffic, hi - lo), np.float32)
                     if lo == 0:
                         shift[(0,) * shift.ndim] += logit_shift
-                    val, g = self._grad(params, *(a[lo:hi] for a in arrays),
+                    val, g = self._grad(jax.tree.unflatten(tree, leaves),
+                                        *(a[lo:hi] for a in arrays),
                                         shift=shift)
                     total = total + val
-                    grads = g if grads is None else \
-                        jax.tree.map(jnp.add, grads, g)
-                grads = jax.tree.map(lambda g: g / n, grads)
+                    grads = g if grads is None else _add(grads, g)
+                    del g
+                grads = jax.tree.leaves(_mean(grads, n))
                 losses.append(float(total) / n)
                 lr = learning_rate(self.opt, k)
-                params, m, v, clipped = self._step(params, m, v, grads, k, lr)
+                scale = self._clip(grads)
+                for i in range(len(leaves)):
+                    leaves[i], mi, vi, grads[i] = self._step(
+                        leaves[i], jax.device_put(m[i]),
+                        jax.device_put(v[i]), grads[i], scale, k, lr)
+                    m[i], v[i] = np.array(mi), np.array(vi)
+                    if k == 0:
+                        grad2[keys[i]] = grad2_norm(
+                            float(_root_norms([vi])[0]), self.opt)
+                    del mi, vi
                 if k == 0:
-                    grad_norms = leaf_norms(clipped)
-                    grad2 = grad_norms_from_moments(m, v, self.opt)[1]
-            change = leaf_norms(jax.tree.map(jnp.subtract, params, p0))
+                    grad_norms = dict(zip(keys, map(float, _norms(grads))))
+                del grads
+            change = {key: float(_change_norm(p, jax.device_put(q)))
+                      for key, p, q in zip(keys, leaves, p0)}
         return {"losses": losses, "grad": grad_norms, "grad2": grad2,
                 "change": change}

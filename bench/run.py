@@ -239,9 +239,11 @@ def run(cell: cells.Cell, seed: int, seconds: float, trace: bool, devices,
     del t, compiled
     gc.collect()
 
+    t_ref = time.perf_counter()
     ref = Reference(cell, device=devices[0]).readings(seed)
     checks, correct = check.judge(check.gaps(prog, ref), tr["limits"])
-    print(f"[bench] grad_gap from the first moment "
+    print(f"[bench] reference {time.perf_counter() - t_ref:.2f} s; "
+          f"grad_gap from the first moment "
           f"{check.leaf_gap(prog['grad'], ref['grad'])!r}, from the second "
           f"{check.leaf_gap(prog['grad2'], ref['grad'])!r}", file=sys.stderr)
 

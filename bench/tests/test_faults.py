@@ -66,8 +66,7 @@ def test_exchange_between_chips_left_out(monkeypatch, family):
     assert not result(cell)["correct"]
 
 
-@FAMILIES
-def test_answer_altered_where_produced(monkeypatch, family):
+def _alter_answer(monkeypatch):
     from repro.models import transformer
     head = transformer._head
 
@@ -76,6 +75,11 @@ def test_answer_altered_where_produced(monkeypatch, family):
         return out.at[(0,) * out.ndim].add(
             jnp.asarray(calibrate.LOGIT_SHIFT, h.dtype))
     monkeypatch.setattr(transformer, "_head", altered)
+
+
+@FAMILIES
+def test_answer_altered_where_produced(monkeypatch, family):
+    _alter_answer(monkeypatch)
     assert not result(small.cell(family))["correct"]
 
 
@@ -88,6 +92,25 @@ def test_wrong_second_moment_decay(monkeypatch, family):
     res = result(small.cell(family))
     assert not res["correct"]
     assert res["checks"]["grad_gap"]["value"] > 0.5
+
+
+ACCUMULATED = {
+    "sound": lambda mp: None,
+    "state_unchanged": lambda mp: _patch_step(
+        mp, lambda f, s, st, b: (st, f(s, st, b)[1])),
+    "half_batch": lambda mp: _patch_step(
+        mp, lambda f, s, st, b: f(s, st, _rows(b, 2))),
+    "altered_answer": _alter_answer,
+}
+
+
+@FAMILIES
+@pytest.mark.parametrize("fault", sorted(ACCUMULATED))
+def test_faults_under_accumulation(monkeypatch, family, fault):
+    # the same faults planted in a step that accumulates two micro-batches
+    ACCUMULATED[fault](monkeypatch)
+    res = result(small.cell(family, accum=2))
+    assert res["correct"] == (fault == "sound"), res["checks"]
 
 
 def test_only_numbers_with_a_limit_are_compared():
